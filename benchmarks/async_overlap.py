@@ -150,6 +150,8 @@ def _child(steps: int, trace_path: str) -> None:
 def main() -> None:
     steps = STEPS                             # >=5 PPO versions, smoke or full
     env = dict(os.environ)
+    # a CPU structure check on fake host devices: never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
     # resolved in the parent: the child may not see run.py's SMOKE flag
     trace_path = os.path.abspath(bench_path("BENCH_async_overlap_trace.json"))

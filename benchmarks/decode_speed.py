@@ -25,8 +25,9 @@ Three arms, all on the SAME tiny model so CI smoke stays cheap:
 * **families** — measured decode tokens/s for one representative of
   each architecture family (transformer / RG-LRU / xLSTM) next to the
   analytic memory-bound roofline (weights + decode state re-read per
-  token, ``launch/roofline.py::decode_gap_rows``).  On CPU the gap vs
-  the TPU-v5e ceiling is tiny; the gate only bands it into (0, 1].
+  token, ``launch/roofline.py::decode_gap_rows``).  The record names
+  the device it ran on; off the chip the gap is "not measured", and the
+  gate holds a CPU record to that label.
 
 Results land in ``BENCH_decode_speed.json`` via ``bench_path`` (smoke
 runs never clobber the committed full-run baseline).  Each timed mode
@@ -351,11 +352,19 @@ def _family_arm(n_requests: int, repeats: int):
             "state_bytes": state_bytes,
             "bytes_per_token": param_bytes + state_bytes,
         }
-    for row in decode_gap_rows({"families": families}):
+    for row in decode_gap_rows({"device": _device(), "families": families}):
         families[row["family"]]["roofline_tok_s"] = row["roofline_tok_s"]
         families[row["family"]]["measured_over_roofline"] = \
             row["measured_over_roofline"]
     return families
+
+
+def _device() -> dict:
+    import jax
+
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
 
 
 def main() -> None:
@@ -365,6 +374,7 @@ def main() -> None:
     spec = _spec_arm(n_requests, repeats)
     families = _family_arm(n_requests, repeats)
     record = {
+        "device": _device(),
         "config": {"n_slots": N_SLOTS, "prompt_len": PROMPT_LEN,
                    "max_gen_len": MAX_GEN, "n_requests": n_requests,
                    "spec_k": SPEC_K, "repeats": repeats},

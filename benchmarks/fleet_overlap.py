@@ -227,6 +227,8 @@ def main() -> None:
     kill_steps = smoke_steps(KILL_STEPS, 2)
     thr_steps = smoke_steps(THR_STEPS, 1)
     env = dict(os.environ)
+    # a CPU structure check on fake host devices: never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.fleet_overlap", "--child",

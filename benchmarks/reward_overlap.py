@@ -184,6 +184,8 @@ def _child(steps: int) -> None:
 def main() -> None:
     steps = STEPS
     env = dict(os.environ)
+    # a CPU structure check on fake host devices: never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.reward_overlap",

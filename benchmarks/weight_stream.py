@@ -497,6 +497,8 @@ def main() -> None:
     thr_steps = smoke_steps(THR_STEPS, 1)
     kill_steps = smoke_steps(KILL_STEPS, 2)
     env = dict(os.environ)
+    # a CPU structure check on fake host devices: never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.weight_stream", "--child",

@@ -1,11 +1,1 @@
-"""AReaL reproduction package.
-
-Importing ``repro`` (any submodule) installs the jax forward-compat
-shims from :mod:`repro.dist.compat`: the codebase and its tests target
-the modern mesh API (``jax.set_mesh``, ``jax.sharding.AxisType``,
-``make_mesh(axis_types=...)``) and the shims backfill it, only where
-missing, on older jaxlib builds.
-"""
-from repro.dist import compat as _compat
-
-_compat.install()
+"""AReaL reproduction package (see README.md and DESIGN.md)."""

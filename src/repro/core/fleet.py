@@ -401,6 +401,33 @@ def build_trainer(*, model_cfg, rl, seed: int, pack_rows: int = 1):
     return PPOTrainer(model, rl, params, pack_rows=pack_rows)
 
 
+def _check_chip_holders(rollout: int, trainer: int,
+                        worker_env: Optional[Dict[str, str]]) -> None:
+    """Refuse to start a fleet whose workers would contend for TPU chips
+    (DESIGN.md §One process per chip).  A chip belongs to one process at
+    a time, and a process claims every chip it can see; this supervisor
+    holds them once it has asked JAX for its platform.  So on a TPU every
+    worker that would initialise JAX there is one process too many, and
+    the fleet fails here, naming the counts, instead of hanging in a
+    worker's start-up.  Workers pinned to the CPU (``JAX_PLATFORMS=cpu``
+    in their environment) hold no chip."""
+    env = {**os.environ, **(worker_env or {})}
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    import jax
+    if jax.default_backend() != "tpu":
+        return
+    chips = len(jax.local_devices())
+    raise RuntimeError(
+        f"FleetRuntime would start {rollout + trainer} chip-holding worker "
+        f"processes ({rollout} rollout + {trainer} trainer) on a host with "
+        f"{chips} TPU chip(s) that this supervisor process already holds; "
+        f"a chip belongs to one process at a time. Use the threaded "
+        f"runtime (one process, rollout/trainer submeshes) or run the "
+        f"workers on the CPU with worker_env={{'JAX_PLATFORMS': 'cpu'}}.")
+
+
 # ---- supervisor-side worker handle + registry -------------------------------
 @dataclass
 class WorkerHandle:
@@ -535,6 +562,12 @@ class FleetRuntime(SchedulerExecutorMixin):
                  stream_chunks_per_step: int = 8,
                  flightrec_dir: Optional[str] = None):
         assert rollout_workers >= 1 and trainer_procs >= 1
+        self.worker_env = worker_env
+        self.max_workers = max_workers or max(rollout_workers * 2,
+                                              rollout_workers + 1)
+        _check_chip_holders(
+            (self.max_workers if elastic else rollout_workers), trainer_procs,
+            worker_env)
         self.sched = scheduler
         self.rl = scheduler.rl
         self.engine_factory = engine_factory
@@ -547,14 +580,11 @@ class FleetRuntime(SchedulerExecutorMixin):
         self.store.subscribe(self._broadcast_weights)
         self.elastic = elastic
         self.min_workers = min_workers
-        self.max_workers = max_workers or max(rollout_workers * 2,
-                                              rollout_workers + 1)
         self.elastic_interval = elastic_interval
         self.heartbeat_s = heartbeat_s
         self.heartbeat_timeout = heartbeat_timeout
         self.startup_timeout = startup_timeout
         self.max_respawns = max_respawns
-        self.worker_env = worker_env
         self.idle_sleep = idle_sleep
         from repro.core.weights import ENCODINGS
         if weight_stream not in ENCODINGS:

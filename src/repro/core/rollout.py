@@ -61,6 +61,8 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, RLConfig
 from repro.core.batching import (BlockAllocator, plan_prefill_chunks,
@@ -177,6 +179,7 @@ class RolloutEngine:
         self.dtype = jnp.float32 if cfg.dtype is None else cfg.dtype
         self._rng = jax.random.key(cfg.seed)
         self._step_count = 0
+        self.mesh = None                   # set by place()
         n_slots = cfg.n_slots
         block_size = cfg.block_size
         cache = cfg.cache
@@ -278,38 +281,61 @@ class RolloutEngine:
             self.cache = model.init_paged_cache(n_slots, self.n_blocks,
                                                 block_size, self.dtype)
             if self.fused_decode == "fused":
-                self._jit_decode_paged = jax.jit(self._decode_paged_fused_fn)
+                self._jit_decode_paged = self._jit(self._decode_paged_fused_fn)
             else:
-                self._jit_decode_paged = jax.jit(self._decode_paged_fn)
+                self._jit_decode_paged = self._jit(self._decode_paged_fn)
             if self.fused_decode == "split":
-                self._jit_decode_logits = jax.jit(self._decode_paged_logits_fn)
-                self._jit_sample = jax.jit(self._sample_only_fn)
-            self._jit_prefill_paged = jax.jit(self._prefill_paged_fn)
+                self._jit_decode_logits = self._jit(self._decode_paged_logits_fn)
+                self._jit_sample = self._jit(self._sample_only_fn)
+            self._jit_prefill_paged = self._jit(self._prefill_paged_fn)
             if self.prefill_chunk:
-                self._jit_chunk_paged = jax.jit(self._chunk_paged_fn)
-                self._jit_chunk_paged_quiet = jax.jit(self._chunk_paged_quiet_fn)
+                self._jit_chunk_paged = self._jit(self._chunk_paged_fn)
+                self._jit_chunk_paged_quiet = self._jit(self._chunk_paged_quiet_fn)
             if self.spec_decode:
-                self._jit_spec_draft = jax.jit(self._spec_draft_paged_fn)
-                self._jit_spec_verify = jax.jit(self._spec_verify_paged_fn)
-                self._jit_spec_commit = jax.jit(self._spec_commit_paged_fn)
+                self._jit_spec_draft = self._jit(self._spec_draft_paged_fn)
+                self._jit_spec_verify = self._jit(self._spec_verify_paged_fn)
+                self._jit_spec_commit = self._jit(self._spec_commit_paged_fn)
         else:
             if self.prefill_chunk and not hasattr(model, "prefill_chunk"):
                 raise ValueError(
                     "prefill_chunk > 0 needs a decoder-only LM with chunked "
                     "prefill support (DESIGN.md §Chunked prefill)")
             self.cache = model.init_cache(n_slots, self.max_len, self.dtype)
-            self._jit_decode = jax.jit(self._decode_fn)
-            self._jit_prefill = jax.jit(self._prefill_fn)
-            self._jit_insert = jax.jit(self.model.cache_insert)
+            self._jit_decode = self._jit(self._decode_fn)
+            self._jit_prefill = self._jit(self._prefill_fn)
+            self._jit_insert = self._jit(self.model.cache_insert)
             if self.prefill_chunk:
-                self._jit_chunk = jax.jit(self._chunk_fn)
-                self._jit_chunk_quiet = jax.jit(self._chunk_quiet_fn)
+                self._jit_chunk = self._jit(self._chunk_fn)
+                self._jit_chunk_quiet = self._jit(self._chunk_quiet_fn)
             if self.spec_decode:
-                self._jit_spec_draft = jax.jit(self._spec_draft_fn)
-                self._jit_spec_verify = jax.jit(self._spec_verify_fn)
-                self._jit_spec_commit = jax.jit(self._spec_commit_fn)
+                self._jit_spec_draft = self._jit(self._spec_draft_fn)
+                self._jit_spec_verify = self._jit(self._spec_verify_fn)
+                self._jit_spec_commit = self._jit(self._spec_commit_fn)
         if self.prefill_chunk:
-            self._jit_reset = jax.jit(self.model.reset_slot_rows)
+            self._jit_reset = self._jit(self.model.reset_slot_rows)
+
+    # ---- placement --------------------------------------------------------
+    def place(self, mesh) -> None:
+        """Commit the params and KV cache to ``mesh``, replicated, and run
+        every step there (DESIGN.md §One process per chip).  Each step
+        runs whole on every chip of the mesh under ``shard_map``, since
+        Pallas kernels cannot be partitioned by XLA, even replicated; a
+        one-chip mesh takes the same path."""
+        rep = NamedSharding(mesh, P())
+        self.mesh = mesh
+        self.params = jax.device_put(self.params, rep)
+        self.cache = jax.device_put(self.cache, rep)
+
+    def _jit(self, fn):
+        """``jax.jit`` of an engine step that honours ``place``: the mesh
+        is read when the step is traced, and a placed engine's committed
+        state makes jit trace again."""
+        def step(*args):
+            if self.mesh is None:
+                return fn(*args)
+            return jax.shard_map(fn, mesh=self.mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False)(*args)
+        return jax.jit(step)
 
     # ---- sampling ---------------------------------------------------------
     def _masked_logits(self, logits):

@@ -24,6 +24,14 @@ from repro.core import advantages as adv_mod
 from repro.core import batching, ppo
 from repro.core.buffer import Trajectory
 
+# The attention the trainer traces its forwards with.  The Pallas flash
+# kernel has no reverse-mode rule (``jax.grad`` through ``pallas_call``
+# fails at trace time), so the differentiated forward runs the jnp
+# oracle; the proximal-logprob recompute uses the same one so that the
+# importance ratio starts at exactly 1.  Rollout engines keep the
+# platform's kernels (DESIGN.md §Kernel backend).
+TRAIN_ATTENTION = "jnp"
+
 
 @dataclass
 class TrainMetrics:
@@ -55,15 +63,30 @@ class PPOTrainer:
 
         self._jit_logprobs = jax.jit(self._logprob_fn)
         self._jit_grad = jax.jit(jax.value_and_grad(self._loss_fn, has_aux=True))
+        # The gradient sum and the optimizer state are the trainer's own,
+        # so the accumulation and the update reuse their buffers in place;
+        # the params are not donated, since the published versions and the
+        # engine may still read them.  At 420 M f32 params (areal-qwen-1.5b
+        # widths, 4 layers) this keeps ~5 GB of copies off one 16 GB chip.
+        self._jit_accumulate = jax.jit(
+            lambda acc, g: jax.tree.map(jnp.add, acc, g), donate_argnums=0)
         self._jit_apply = jax.jit(
-            lambda p, g, s: optim.apply_updates(self.adam, p, g, s))
+            lambda p, g, s, n: optim.apply_updates(
+                self.adam, p, jax.tree.map(lambda x: x / n, g), s),
+            donate_argnums=(1, 2))
 
     # ---- jit bodies -------------------------------------------------------
     def _forward_logprobs(self, params, batch):
+        # imported here, not at the top: the kernels load Pallas, which
+        # processes that import repro.core without tracing a model (fleet
+        # workers with simulated engines and trainers) need not pay for
+        from repro.kernels import ops
+
         seg = batch["segment_ids"]
-        hidden, aux = self.model.hidden_states(
-            params, batch["tokens"], positions=batch["positions"],
-            segment_ids=seg)
+        with ops.use_backend(TRAIN_ATTENTION):
+            hidden, aux = self.model.hidden_states(
+                params, batch["tokens"], positions=batch["positions"],
+                segment_ids=seg)
         logits = self.model.logits(params, hidden)
         lp = ppo.next_token_logprobs(logits, batch["tokens"])
         # token t's predictor (t-1) must be in the same segment
@@ -121,18 +144,21 @@ class PPOTrainer:
             n_static = max(self.rl.min_microbatches,
                            int(np.ceil(sum(lens) / cap)) * 2)
             groups = batching.static_batching(lens, n_static)
+        # micro-batches go straight to the devices holding the params
+        # (the trainer submesh when disaggregated), not the default device
+        place = getattr(jax.tree.leaves(self.params)[0], "sharding", None)
         mbs = []
         for g in groups:
             pb = batching.pack_sequences([seqs[i] for i in g], self.pack_len,
                                          rows=self.pack_rows)
-            mbs.append({
-                "tokens": jnp.asarray(pb.tokens),
-                "positions": jnp.asarray(pb.positions),
-                "segment_ids": jnp.asarray(pb.segment_ids),
-                "loss_mask": jnp.asarray(pb.loss_mask),
-                "advantages": jnp.asarray(pb.advantages),
-                "behav_logprob": jnp.asarray(pb.behav_logprob),
-            })
+            mbs.append(jax.device_put({
+                "tokens": pb.tokens,
+                "positions": pb.positions,
+                "segment_ids": pb.segment_ids,
+                "loss_mask": pb.loss_mask,
+                "advantages": pb.advantages,
+                "behav_logprob": pb.behav_logprob,
+            }, place))
         return mbs
 
     # ---- the train step ----------------------------------------------------
@@ -162,12 +188,14 @@ class PPOTrainer:
             for i in idx:
                 (loss, diag), g = self._jit_grad(self.params, mbs[i])
                 loss_acc += float(loss)
-                grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
+                grads = g if grads is None else self._jit_accumulate(grads, g)
                 for k, v in diag.items():
                     diag_acc[k] = diag_acc.get(k, 0.0) + float(v)
-            grads = jax.tree.map(lambda x: x / len(idx), grads)
+            # the mean over the split is taken inside the update, in place
             self.params, self.opt_state, om = self._jit_apply(
-                self.params, grads, self.opt_state)
+                self.params, grads, self.opt_state, float(len(idx)))
+            diag_acc["grad_norm"] = (diag_acc.get("grad_norm", 0.0)
+                                     + float(om["grad_norm"]) * len(idx))
             total_loss += loss_acc / len(idx)
             n_applied += len(idx)
 

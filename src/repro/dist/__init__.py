@@ -5,16 +5,8 @@ that are exact no-ops when no mesh is active, so every model file can be
 written once and run identically on a laptop (1 device, no mesh) and on
 a pod mesh.  ``sharding`` holds the path-based parameter partition rules
 and the pytree-level spec builders the pjit call sites consume.
-
-Importing this package installs the jax forward-compat shims (see
-``compat``): model/launch/test code targets the modern mesh API and the
-shims backfill it on older jaxlib builds.
 """
-from repro.dist import compat as _compat
-
-_compat.install()
-
-from repro.dist import constraints, sharding                     # noqa: E402
-from repro.dist.constraints import constrain, constrain_qkv      # noqa: E402
+from repro.dist import constraints, sharding
+from repro.dist.constraints import constrain, constrain_qkv
 
 __all__ = ["constraints", "sharding", "constrain", "constrain_qkv"]

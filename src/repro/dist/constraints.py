@@ -18,10 +18,23 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-
-from repro.dist import compat
+from jax._src.mesh import thread_resources
 
 DATA_AXES = ("pod", "data")     # data-parallel axes, outermost first
+
+
+def active_mesh():
+    """The ambient mesh, or None outside any mesh scope: the mesh of
+    ``with jax.set_mesh(m):`` (as the abstract mesh, which is what a
+    traced function sees), else that of a ``with m:`` block (which only
+    the physical-mesh context reports).  Inside a ``shard_map`` body the
+    program is per device, so there is nothing to constrain: None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        manual = jax.sharding.AxisType.Manual in mesh.axis_types
+        return None if manual else mesh
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 def axis_sizes(mesh) -> dict:
@@ -83,7 +96,7 @@ def resolve_spec(mesh, shape: Sequence[int], axes) -> jax.sharding.PartitionSpec
 
 def constrain(x, *axes):
     """Tag ``x`` with mesh axes; exact no-op when no mesh is active."""
-    mesh = compat.active_mesh()
+    mesh = active_mesh()
     if mesh is None:
         return x
     spec = resolve_spec(mesh, x.shape, axes)
@@ -102,7 +115,7 @@ def constrain_qkv(q, k, v, *, batch_axis: Optional[str] = "dp"):
     the flash-attention contraction then broadcasts kv per model shard,
     which is exactly the memory/compute layout a GQA TP scheme wants.
     """
-    mesh = compat.active_mesh()
+    mesh = active_mesh()
     if mesh is None:
         return q, k, v
     q = constrain(q, batch_axis, None, "model", None)

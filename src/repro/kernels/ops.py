@@ -1,10 +1,21 @@
 """Jit-friendly kernel entry points with backend dispatch + padding.
 
-Backends:
-  * ``"jnp"``               pure-jnp reference (default; CPU + dry-run path)
+Backends (DESIGN.md §Kernel backend):
+  * ``"pallas"``            compiled Pallas — what runs on a TPU
+  * ``"jnp"``               the pure-jnp oracles of ``ref.py`` — what
+                            runs on every other platform
   * ``"pallas_interpret"``  Pallas kernel bodies executed by the
-                            interpreter (CPU correctness validation)
-  * ``"pallas"``            compiled Pallas (real TPU)
+                            interpreter; only tests ask for it, per call
+
+The backend is a platform rule, decided when a call is traced:
+``platform_backend`` gives ``"pallas"`` when JAX's default backend is a
+TPU and ``"jnp"`` otherwise.  Code that must trace one backend on
+purpose scopes it with ``use_backend``: the trainer's differentiated
+forward (``repro.core.trainer.TRAIN_ATTENTION``, since the Pallas
+kernels have no reverse-mode rule), and compile checks that target a
+described TPU from a CPU host.  Two jnp branches are decisions, not
+fallbacks: cross-attention (Sq != Sk) and ring-cache prefill
+continuation are not on the TPU serving path (the paged engine is).
 
 The wrappers pad sequence/cache/channel dims to hardware-aligned block
 multiples (and head_dim to a lane multiple of 128) before calling the
@@ -12,8 +23,11 @@ Pallas kernels, then slice back — callers never see alignment.
 """
 from __future__ import annotations
 
-from typing import Optional
+import contextlib
+import contextvars
+from typing import Iterator, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
@@ -24,21 +38,48 @@ from repro.kernels.linear_scan import linear_scan_pallas
 from repro.kernels.paged_decode_attention import paged_decode_attention_pallas
 from repro.kernels.paged_prefill_attention import paged_prefill_attention_pallas
 
-_BACKEND = "jnp"
+BACKENDS = ("jnp", "pallas", "pallas_interpret")
 _LANE = 128
+# rows per block: a whole (16, 128) tile of a 16-bit dtype (two f32 tiles)
+_SUBLANE = 16
 # switch to the q-chunked flash pattern when the full score matrix would
 # exceed ~ (1024 x 1024) per (batch, head) — keeps dry-run memory sane
 _CHUNKED_THRESHOLD = 1024 * 1024
+_SCOPED: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
+    "kernel_backend", default=None)
 
 
-def set_backend(backend: str) -> None:
-    global _BACKEND
-    assert backend in ("jnp", "pallas_interpret", "pallas"), backend
-    _BACKEND = backend
+def platform_backend() -> str:
+    """The platform rule: compiled Pallas on a TPU, jnp oracles elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
-def get_backend() -> str:
-    return _BACKEND
+@contextlib.contextmanager
+def use_backend(backend: str) -> Iterator[None]:
+    """Trace the calls made inside this scope (this thread) with
+    ``backend`` ("jnp" or "pallas").  A jitted function keeps what it was
+    traced with, so the scope must enclose its first call or sit inside
+    its body."""
+    if backend not in ("jnp", "pallas"):
+        raise ValueError(f"use_backend takes 'jnp' or 'pallas', not {backend!r}")
+    token = _SCOPED.set(backend)
+    try:
+        yield
+    finally:
+        _SCOPED.reset(token)
+
+
+def current_backend() -> str:
+    """The backend a call traced here gets: the scoped one, else the
+    platform's."""
+    return _SCOPED.get() or platform_backend()
+
+
+def _resolve(backend: Optional[str]) -> str:
+    backend = backend or current_backend()
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown kernel backend {backend!r}")
+    return backend
 
 
 def _round_up(x: int, m: int) -> int:
@@ -61,7 +102,7 @@ def _pad_axis(x, axis: int, target: int, value=0):
 def flash_attention(q, k, v, segment_ids=None, *, causal: bool = True,
                     window: int = 0, softmax_scale: Optional[float] = None,
                     backend: Optional[str] = None):
-    backend = backend or _BACKEND
+    backend = _resolve(backend)
     if backend == "jnp" or q.shape[1] != k.shape[1]:
         # cross-attention (Sq != Sk) stays on the jnp path
         if q.shape[1] * k.shape[1] > _CHUNKED_THRESHOLD:
@@ -73,7 +114,7 @@ def flash_attention(q, k, v, segment_ids=None, *, causal: bool = True,
                                     softmax_scale=softmax_scale)
     b, s, h, hd = q.shape
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
-    bq = bk = min(128, _round_up(s, 8))
+    bq = bk = min(128, _round_up(s, _SUBLANE))
     sp = _round_up(s, max(bq, bk))
     hdp = _round_up(hd, _LANE)
     if segment_ids is None:
@@ -95,14 +136,14 @@ def flash_attention(q, k, v, segment_ids=None, *, causal: bool = True,
 def decode_attention(q, k_cache, v_cache, cache_pos, t, *, window: int = 0,
                      softmax_scale: Optional[float] = None,
                      backend: Optional[str] = None):
-    backend = backend or _BACKEND
+    backend = _resolve(backend)
     if backend == "jnp":
         return _ref.decode_attention(q, k_cache, v_cache, cache_pos, t,
                                      window=window, softmax_scale=softmax_scale)
     b, h, hd = q.shape
     w = k_cache.shape[1]
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
-    bw = min(256, _round_up(w, 8))
+    bw = min(256, _round_up(w, _SUBLANE))
     wp = _round_up(w, bw)
     hdp = _round_up(hd, _LANE)
     qp = _pad_axis(q, 2, hdp)
@@ -125,7 +166,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, t, *,
                            backend: Optional[str] = None):
     """q: (B, H, hd); pools: (N, bs, Hkv, hd); block_tables: (B, E) int32
     (-1 = unbound); t: (B,) int32.  See DESIGN.md §Paged KV-cache pool."""
-    backend = backend or _BACKEND
+    backend = _resolve(backend)
     if backend == "jnp":
         return _ref.paged_decode_attention(q, k_pool, v_pool, block_tables, t,
                                            window=window,
@@ -156,7 +197,7 @@ def fused_decode_tail(q, k_pool, v_pool, wo, block_tables, t, *,
     materializing the (B, H, hd) contexts (DESIGN.md §Fused decode
     tail).  wo is padded per head (the pad rows multiply the padded
     context columns, which are zero)."""
-    backend = backend or _BACKEND
+    backend = _resolve(backend)
     if backend == "jnp":
         return _ref.fused_decode_tail(q, k_pool, v_pool, wo, block_tables, t,
                                       window=window,
@@ -205,14 +246,14 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
     (B, C) (-1 = padded row); pools: (N, bs, Hkv, hd); block_tables:
     (B, E) int32 (-1 = unbound).  The chunk's own K/V must already be in
     the pool (write-then-read).  See DESIGN.md §Chunked prefill."""
-    backend = backend or _BACKEND
+    backend = _resolve(backend)
     if backend == "jnp":
         return _ref.paged_prefill_attention(q, k_pool, v_pool, block_tables,
                                             q_pos, window=window,
                                             softmax_scale=softmax_scale)
     b, c, h, hd = q.shape
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
-    cp = _round_up(c, 8)
+    cp = _round_up(c, _SUBLANE)
     hdp = _round_up(hd, _LANE)
     qp = _pad_axis(_pad_axis(q, 1, cp), 3, hdp)
     kp = _pad_axis(k_pool, 3, hdp)
@@ -229,11 +270,11 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, q_pos, *,
 # ---------------------------------------------------------------------------
 
 def linear_scan(a, x, h0=None, *, backend: Optional[str] = None):
-    backend = backend or _BACKEND
+    backend = _resolve(backend)
     if backend == "jnp":
         return _ref.linear_scan(a, x, h0)
     b, s, c = a.shape
-    bt = min(256, _round_up(s, 8))
+    bt = min(256, _round_up(s, _SUBLANE))
     bc = min(256, _round_up(c, _LANE))
     sp, cp = _round_up(s, bt), _round_up(c, bc)
     ap = _pad_axis(_pad_axis(a, 1, sp), 2, cp)       # padded a=0 keeps carry math finite

@@ -12,8 +12,10 @@ call (blocks never wrap, so write-then-read is exact).
 The grid iterates (slot, q-head, table-entry) with the table-entry axis
 sequential, reusing the block-table gather of the decode kernel: the
 table is a scalar-prefetch operand and the BlockSpec index map streams
-exactly the physical (bs, hd) tile entry e names.  Per-query absolute
-positions arrive as a (1, C) VMEM operand; masking is purely positional
+exactly the physical (bs, hd) tile entry e names.  Heads sit side by
+side on the lane axis of the query and pool operands, so every block's
+last two dimensions are whole TPU tiles.  Per-query absolute positions
+arrive as a (C, 128) VMEM operand (broadcast across lanes); masking is purely positional
 (entry unbound, key beyond the query, or outside the sliding window), so
 partial blocks, padded queries (q_pos = -1), and windows need no special
 cases.  Each step folds its tile into per-query online-softmax running
@@ -46,9 +48,9 @@ def _kernel(tables_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     blk = tables_ref[ib, e]                              # physical block id
-    q = q_ref[0, :, 0, :].astype(jnp.float32)            # (C, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
+    q = q_ref[...].astype(jnp.float32)                   # (C, hd)
+    k = k_ref[...].astype(jnp.float32)                   # (bs, hd)
+    v = v_ref[...].astype(jnp.float32)                   # (bs, hd)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
@@ -56,7 +58,7 @@ def _kernel(tables_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
     # [e*bs, (e+1)*bs)); query positions come from the qpos operand.
     # Unbound entries (-1) and padded queries (q_pos = -1) mask out.
     kpos = e * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    qpos = qpos_ref[0, :][:, None]                       # (C, 1)
+    qpos = qpos_ref[...][:, :1]                          # (C, 1)
     mask = (blk >= 0) & (kpos <= qpos) & (qpos >= 0)
     if window > 0:
         mask &= kpos > qpos - window
@@ -74,50 +76,58 @@ def _kernel(tables_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
     @pl.when(e == ne - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_prefill_attention_pallas(q, k_pool, v_pool, block_tables, q_pos, *,
                                    window=0, softmax_scale=None,
-                                   interpret=True):
+                                   interpret=False):
     """q: (B, C, H, hd); pools: (N, bs, Hkv, hd); block_tables: (B, E)
     int32 (-1 = unbound entry); q_pos: (B, C) int32 absolute query
-    positions (-1 = padded query row, output unspecified)."""
+    positions (-1 = padded query row, output unspecified).  C must be a
+    multiple of 16 and hd of 128 (``ops`` pads them)."""
     b, c, h, hd = q.shape
     n, bs, hkv, _ = k_pool.shape
     e = block_tables.shape[1]
     group = h // hkv
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
+    # Tile-aligned layouts: heads side by side on the lane axis (a free
+    # reshape; a head is a lane-aligned hd-wide column block), and query
+    # positions broadcast across 128 lanes so their block is a whole
+    # (C, 128) tile; the kernel reads lane 0.
+    qf = q.reshape(b, c, h * hd)
+    kf = k_pool.reshape(n, bs, hkv * hd)
+    vf = v_pool.reshape(n, bs, hkv * hd)
+    qpos = jnp.broadcast_to(q_pos.astype(jnp.int32)[:, :, None], (b, c, 128))
 
-    grid = (b, h, e)
+    # the same paged gather as the decode kernel: the physical pool block
+    # streamed at (b, h, e) is whatever the slot's table names (clamped;
+    # unbound -1 entries are masked out).
+    kv_spec = pl.BlockSpec((None, bs, hd),
+                           lambda b_, h_, e_, bt, g=group:
+                           (jnp.maximum(bt[b_, e_], 0), 0, h_ // g))
+    q_spec = pl.BlockSpec((None, c, hd), lambda b_, h_, e_, bt: (b_, 0, h_))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                  # block_tables
-        grid=grid,
+        grid=(b, h, e),
         in_specs=[
-            pl.BlockSpec((1, c, 1, hd), lambda b_, h_, e_, bt: (b_, 0, h_, 0)),
-            # the same paged gather as the decode kernel: the physical
-            # pool block streamed at (b, h, e) is whatever the slot's
-            # table names (clamped; unbound -1 entries are masked out).
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b_, h_, e_, bt, g=group:
-                         (jnp.maximum(bt[b_, e_], 0), 0, h_ // g, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b_, h_, e_, bt, g=group:
-                         (jnp.maximum(bt[b_, e_], 0), 0, h_ // g, 0)),
-            pl.BlockSpec((1, c), lambda b_, h_, e_, bt: (b_, 0)),
+            q_spec, kv_spec, kv_spec,
+            pl.BlockSpec((None, c, 128), lambda b_, h_, e_, bt: (b_, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, c, 1, hd),
-                               lambda b_, h_, e_, bt: (b_, 0, h_, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((c, hd), jnp.float32),
             pltpu.VMEM((c, 1), jnp.float32),
             pltpu.VMEM((c, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window, bs=bs, ne=e),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), q, k_pool, v_pool,
-      q_pos.astype(jnp.int32))
+        name="paged_prefill_attention",
+    )(block_tables.astype(jnp.int32), qf, kf, vf, qpos)
+    return out.reshape(b, c, h, hd)

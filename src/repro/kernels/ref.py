@@ -2,8 +2,9 @@
 
 These are the semantics of record: the Pallas kernels must match them
 (tests sweep shapes/dtypes and assert_allclose), and they are also the
-default execution path on CPU / in the dry-run (Pallas TPU kernels do not
-lower on the CPU backend; ``interpret=True`` validates the kernel bodies).
+execution path on every platform but the TPU (DESIGN.md §Kernel backend:
+Pallas TPU kernels do not lower on the CPU backend; tests validate the
+kernel bodies with ``backend="pallas_interpret"``).
 """
 from __future__ import annotations
 

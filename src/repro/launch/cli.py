@@ -25,14 +25,66 @@ ENVIRONMENT, RUNTIME and GATEWAY flag is defined:
 ``engine_config_from_args`` is the one bridge from parsed args to a
 validated ``EngineConfig`` — launchers never assemble engine kwargs by
 hand, so a new engine option is added exactly twice (the dataclass
-field and its flag) instead of once per launcher.
+field and its flag) instead of once per launcher.  ``add_model_flags``
+/ ``model_config`` do the same for the model (``--arch``, ``--scale``),
+and ``enable_compile_cache`` places JAX's persistent compilation cache
+for every launcher (DESIGN.md §Compile cache).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import pathlib
 from typing import Dict, Optional
 
+from repro.configs import get_model_config, reduced
+from repro.configs.base import ModelConfig
 from repro.core.config import EngineConfig
+from repro.data import tokenizer
+
+# <checkout>/.jax_cache — a fixed path, because the path is part of what
+# the cache is keyed on: a directory that moves between runs never hits
+_CHECKOUT_CACHE = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is:
+    JAX reads the variable itself and nothing else is set in code.
+    Otherwise the cache goes to ``<checkout>/.jax_cache``.  Launchers
+    call this at start; importing ``repro`` never does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE))
+    return str(_CHECKOUT_CACHE)
+
+
+def add_model_flags(ap: argparse.ArgumentParser) -> None:
+    """The model a launcher builds: ``--arch`` and ``--scale``."""
+    ap.add_argument("--arch", default="areal-qwen-1.5b")
+    ap.add_argument("--scale", default="laptop", choices=["laptop", "pod"],
+                    help="laptop = the architecture's reduced smoke "
+                         "variant with the math tokenizer's vocabulary; "
+                         "pod = the published config at full width, depth "
+                         "and vocabulary")
+
+
+def model_config(arch: str, scale: str) -> ModelConfig:
+    """``--arch``/``--scale`` to a ``ModelConfig``.  At ``pod`` the
+    published config is returned unchanged (its vocabulary contains the
+    math tokenizer's ids); at ``laptop`` the reduced variant is sized to
+    the tokenizer."""
+    cfg = get_model_config(arch)
+    if scale == "pod":
+        return cfg
+    if scale != "laptop":
+        raise ValueError(f"scale must be 'laptop' or 'pod', not {scale!r}")
+    cfg = reduced(cfg)
+    return dataclasses.replace(cfg, vocab_size=tokenizer.VOCAB_SIZE,
+                               name=cfg.name + "-math")
 
 
 def add_engine_flags(ap: argparse.ArgumentParser, *, dryrun: bool = False,

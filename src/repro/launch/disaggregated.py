@@ -34,8 +34,6 @@ def split_devices(train_fraction: float = 0.25, *, model_parallel: int = 1,
 
     def mk(devs):
         arr = np.array(devs).reshape(len(devs) // model_parallel, model_parallel)
-        # no axis_types: implicit Auto on every jax version (0.4.x Mesh
-        # rejects the tuple form the newer API takes)
         return Mesh(arr, ("data", "model"))
 
     return mk(devices[:n_roll]), mk(devices[n_roll:n_roll + n_train])

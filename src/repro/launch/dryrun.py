@@ -270,8 +270,6 @@ def build_dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
             "alias_bytes": int(ma.alias_size_in_bytes),
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):          # older jax: per-program list
-            ca = ca[0] if ca else {}
         rec["cost"] = {"flops_raw": float(ca.get("flops", 0.0)),
                        "bytes_accessed_raw": float(ca.get("bytes accessed", 0.0))}
         # trip-count-corrected static analysis (see hlo_analysis.py: XLA's

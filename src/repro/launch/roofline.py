@@ -1,10 +1,10 @@
 """Roofline analysis over dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Terms per (arch x shape x mesh), hardware = TPU v5e:
+Terms per (arch x shape x mesh), hardware = TPU v5e (``PEAKS``):
 
   compute    = FLOPs_per_device / peak            (197 bf16 TFLOP/s)
   memory     = HBM_bytes_per_device / bw          (819 GB/s)
-  collective = collective_bytes_per_device / link (50 GB/s ICI)
+  collective = collective_bytes_per_device / link (50 GB/s per ICI link)
 
 FLOPs/bytes are the trip-count-corrected statics from hlo_analysis.py
 (the compiled module is the per-device program, so they are per-device
@@ -26,10 +26,36 @@ import os
 from dataclasses import dataclass
 from typing import Dict, List
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
-HBM_PER_CHIP = 16e9        # v5e
+
+@dataclass(frozen=True)
+class Peaks:
+    flops: float          # dense bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    hbm_bytes: float      # HBM capacity
+    link_bw: float        # bytes/s of one chip-to-chip link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of interchip interconnect
+# (taken here as four links of 50 GB/s).  A kind that is not listed is
+# an error, never a default.
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                         link_bw=50e9),
+}
+# the chip the dry-run compiles for and the benchmark targets
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; raises for a kind that is
+    not in ``PEAKS``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 
 @dataclass
@@ -70,9 +96,10 @@ def row_from_record(rec: Dict) -> RooflineRow:
     coll = sum(v for k, v in rec["collectives"].items()
                if not k.endswith("_count"))
     n_dev = rec["n_devices"]
-    c = flops / PEAK_FLOPS
-    m = nbytes / HBM_BW
-    x = coll / LINK_BW
+    hw = peaks(TARGET_KIND)
+    c = flops / hw.flops
+    m = nbytes / hw.hbm_bw
+    x = coll / hw.link_bw
     dom = max((c, "compute"), (m, "memory"), (x, "collective"))[1]
     useful = rec["model_flops"] / max(flops * n_dev, 1e-9)
     mem = rec["memory"]
@@ -80,7 +107,7 @@ def row_from_record(rec: Dict) -> RooflineRow:
         - mem.get("alias_bytes", 0)
     return RooflineRow(rec["arch"], rec["shape"], rec["mesh"], variant,
                        c, m, x, dom, useful, mem["temp_bytes"] / 2**30,
-                       dev_bytes <= HBM_PER_CHIP, "ok", "", rec)
+                       dev_bytes <= hw.hbm_bytes, "ok", "", rec)
 
 
 def load_rows(dirpath: str) -> List[RooflineRow]:
@@ -124,32 +151,45 @@ def to_markdown(rows: List[RooflineRow]) -> str:
 DECODE_FAMILIES = ("transformer", "rg-lru", "xlstm")
 
 
-def decode_roofline_tokens_per_s(bytes_per_token: float) -> float:
+NOT_MEASURED = "not measured"
+
+
+def decode_roofline_tokens_per_s(bytes_per_token: float,
+                                 device_kind: str = TARGET_KIND) -> float:
     """Single-stream decode is memory-bound: every generated token
     re-reads the full weights plus the request's decode state (KV blocks
     for attention, the fixed recurrent state for RG-LRU/xLSTM), so the
-    hardware ceiling is HBM_BW / bytes_per_token."""
-    return HBM_BW / max(float(bytes_per_token), 1.0)
+    hardware ceiling is the HBM bandwidth / bytes_per_token."""
+    return peaks(device_kind).hbm_bw / max(float(bytes_per_token), 1.0)
 
 
 def decode_gap_rows(bench: Dict) -> List[Dict]:
     """Measured-vs-roofline decode throughput per architecture family.
 
-    Consumes the ``families`` section of benchmarks/decode_speed.py
-    output: each entry carries measured ``tokens_per_s`` plus the
-    analytic ``bytes_per_token`` split into ``param_bytes`` (weights
-    re-read every step) and ``state_bytes`` (the family's decode state —
-    the term the family actually differentiates: growing KV for
-    transformers, O(1) recurrent state for RG-LRU and xLSTM).  The gap
-    ``measured_over_roofline`` is clamped to (0, 1]."""
+    Consumes benchmarks/decode_speed.py output: ``device`` names the
+    platform and kind the run took place on, and each ``families`` entry
+    carries measured ``tokens_per_s`` plus the analytic
+    ``bytes_per_token`` split into ``param_bytes`` (weights re-read
+    every step) and ``state_bytes`` (the family's decode state — the
+    term the family actually differentiates: growing KV for
+    transformers, O(1) recurrent state for RG-LRU and xLSTM).  On a TPU
+    the gap ``measured_over_roofline`` divides by that kind's ceiling,
+    clamped to (0, 1]; a run on any other platform measured nothing on
+    the chip, so its gap is ``"not measured"`` and the ceiling shown is
+    the target chip's."""
+    dev = bench.get("device", {})
+    on_chip = dev.get("platform") == "tpu"
+    kind = dev["kind"] if on_chip else TARGET_KIND
     rows = []
     for fam, f in sorted(bench.get("families", {}).items()):
-        ceil = decode_roofline_tokens_per_s(f["bytes_per_token"])
+        ceil = decode_roofline_tokens_per_s(f["bytes_per_token"], kind)
         rows.append({
             "family": fam,
             "measured_tok_s": f["tokens_per_s"],
             "roofline_tok_s": ceil,
-            "measured_over_roofline": min(1.0, f["tokens_per_s"] / ceil),
+            "roofline_kind": kind,
+            "measured_over_roofline": (min(1.0, f["tokens_per_s"] / ceil)
+                                       if on_chip else NOT_MEASURED),
             "dominant_bytes": ("weights" if f["param_bytes"]
                                >= f["state_bytes"] else "state"),
         })
@@ -160,9 +200,10 @@ def decode_gap_report(bench: Dict) -> str:
     out = ["| family | measured tok/s | roofline tok/s | gap | dominant |",
            "|---|---|---|---|---|"]
     for r in decode_gap_rows(bench):
+        gap = r["measured_over_roofline"]
+        gap = gap if isinstance(gap, str) else f"{gap:.2e}"
         out.append(f"| {r['family']} | {r['measured_tok_s']:.1f} "
-                   f"| {r['roofline_tok_s']:.3e} "
-                   f"| {r['measured_over_roofline']:.2e} "
+                   f"| {r['roofline_tok_s']:.3e} | {gap} "
                    f"| {r['dominant_bytes']} |")
     return "\n".join(out)
 

@@ -13,13 +13,14 @@ Two modes share one engine + gateway construction path:
     print a JSON summary.
 
     PYTHONPATH=src python -m repro.launch.serve --cache paged --prefill-chunk 16
+    PYTHONPATH=src python -m repro.launch.serve --scale pod --cache paged \
+        --prefill-chunk 64 --fused-decode fused --prompt-len 64 --max-gen 64
     PYTHONPATH=src python -m repro.launch.serve --cache paged --evict lru \
         --port 8000 --sla-ms 2000
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import time
 from types import SimpleNamespace
@@ -27,9 +28,7 @@ from types import SimpleNamespace
 import jax
 
 from repro import checkpoint
-from repro.configs import get_model_config, reduced
 from repro.core import RolloutEngine
-from repro.data import tokenizer
 from repro.env import make_env
 from repro.launch import cli
 from repro.models.model import build_model
@@ -37,11 +36,12 @@ from repro.obs import trace
 from repro.serve import Gateway, GatewayServer
 
 
-def build_gateway(args):
+def build_gateway(args, **engine_overrides):
     """Model + engine + gateway from parsed flags (shared by both
-    modes and by the gateway-smoke CI job)."""
-    cfg = dataclasses.replace(reduced(get_model_config(args.arch)),
-                              vocab_size=tokenizer.VOCAB_SIZE)
+    modes and by the gateway-smoke CI job).  ``engine_overrides`` win
+    over the flags (``cli.engine_config_from_args``) — a caller asking
+    for greedy decoding passes ``temperature=0.0``."""
+    cfg = cli.model_config(args.arch, args.scale)
     model = build_model(cfg, remat=False)
     params = model.init(jax.random.key(args.seed))
     if args.ckpt:
@@ -58,13 +58,18 @@ def build_gateway(args):
         # the gateway resumes preempted requests through the chunked
         # ingest queue; default to one-span-per-step prompt ingestion
         overrides["prefill_chunk"] = args.prompt_len
-    ec = cli.engine_config_from_args(args, **overrides)
+    ec = cli.engine_config_from_args(args, **{**overrides, **engine_overrides})
     engine = RolloutEngine(model, params, cfg=ec)
     return Gateway(engine), env
 
 
-def run_offline(gw: Gateway, env, args) -> dict:
+def run_offline(gw: Gateway, env, args, results=None) -> dict:
+    """Submit ``args.requests`` requests drawn from ``env``, pump the
+    gateway until idle, verify every answer, and return the summary.
+    ``results``, when given, receives each request's prompt and its
+    end record (tokens, logprobs, ...) keyed by request id."""
     answers = {}
+    prompts = {}
     t0 = time.time()
     for i in range(args.requests):
         p = env.sample()
@@ -72,6 +77,7 @@ def run_offline(gw: Gateway, env, args) -> dict:
         rid = gw.submit(p.prompt_tokens, session=sid,
                         sla=args.sla_ms or None, answer=p.answer)
         answers[rid] = p.answer
+        prompts[rid] = list(p.prompt_tokens)
     ticks = gw.run_until_idle()
     dt = time.time() - t0
     n_ok = 0
@@ -79,6 +85,8 @@ def run_offline(gw: Gateway, env, args) -> dict:
     for rid, ans in answers.items():
         d = gw.drain(rid)
         assert d["end"] is not None, f"request {rid} never finished"
+        if results is not None:
+            results[rid] = {"prompt": prompts[rid], **d["end"]}
         toks += len(d["tokens"])
         fin = SimpleNamespace(response=d["tokens"], answer=ans,
                               prompt=[], rid=rid)
@@ -102,9 +110,9 @@ def run_offline(gw: Gateway, env, args) -> dict:
     return out
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="areal-qwen-1.5b")
+    cli.add_model_flags(ap)
     ap.add_argument("--requests", type=int, default=32,
                     help="offline mode: synthetic requests to serve")
     ap.add_argument("--ckpt", default="", help="load weights from checkpoint")
@@ -112,7 +120,12 @@ def main():
     cli.add_env_flags(ap, default="math", allow_legacy=False)
     cli.add_gateway_flags(ap)
     cli.add_obs_flags(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    cli.enable_compile_cache()
     cli.obs_setup(args, actor="serve")
 
     gw, env = build_gateway(args)

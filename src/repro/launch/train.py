@@ -6,9 +6,12 @@ scale and under a selected executor:
 
   * ``--scale laptop``  (default): reduced model on the local devices —
     the runnable end-to-end driver (examples/ wrap this).
-  * ``--scale pod``: full config on the production mesh.  On real TPU
-    hardware this trains; in this container it validates end-to-end
-    lowering (use launch/dryrun.py for the full matrix).
+  * ``--scale pod``: the published config (``launch/cli.py::
+    model_config``).  Its training state must fit the trainer's devices:
+    areal-qwen-1.5b at full depth needs ~16 bytes per parameter (f32
+    params, Adam m/v, grads), 24.6 GB, more than one 16 GB v5e chip.  A
+    caller that cuts the depth passes a ``ModelConfig`` to
+    ``run_training`` instead of an arch id (``chip_smoke.py`` does).
 
   * ``--runtime virtual`` (default): the deterministic virtual-clock
     executor (core/controller.py) — real computation, simulated
@@ -38,19 +41,17 @@ group.  Every flag is documented in docs/OPERATIONS.md.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import time
+from typing import Union
 
 import jax
 
-from repro.configs import get_model_config, reduced
-from repro.configs.base import RLConfig
+from repro.configs.base import ModelConfig, RLConfig
 from repro.core.config import EngineConfig
 from repro.core import (AsyncRLController, AsyncScheduler, PPOTrainer,
                         ParameterStore, RolloutEngine, ThreadedRuntime)
 from repro.core.simulator import HardwareModel, WorkloadModel, make_llm_timing
-from repro.data import tokenizer
 from repro.data.dataset import PromptStream
 from repro.launch import cli, disaggregated
 from repro.models.model import build_model
@@ -66,7 +67,7 @@ def _place_disaggregated(engine, trainer, train_fraction: float):
     from jax.sharding import PartitionSpec as P
 
     roll_mesh, train_mesh = disaggregated.split_devices(train_fraction)
-    engine.params = disaggregated.push_weights(engine.params, roll_mesh)
+    engine.place(roll_mesh)
     train_sharding = NamedSharding(train_mesh, P())
     trainer.params = jax.device_put(trainer.params, train_sharding)
     trainer.opt_state = jax.device_put(trainer.opt_state, train_sharding)
@@ -83,7 +84,8 @@ def _make_env(env: str, *, seed: int, max_operand: int, sandbox_timeout: float):
     return make_env(env, **kwargs)
 
 
-def run_training(arch: str = "areal-qwen-1.5b", *, steps: int = 25,
+def run_training(arch: Union[str, ModelConfig] = "areal-qwen-1.5b", *,
+                 steps: int = 25,
                  scale: str = "laptop", eta: int = 4, decoupled: bool = True,
                  interruptible: bool = True, batch_size: int = 32,
                  answers_per_prompt: int = 4, n_slots: int = 16,
@@ -113,18 +115,21 @@ def run_training(arch: str = "areal-qwen-1.5b", *, steps: int = 25,
     verification delay instead.  "multiturn" installs the engine
     continuation hook (requires chunked prefill; enabled automatically).
 
+    ``arch`` is an architecture id, sized by ``scale``
+    (``cli.model_config``), or a ``ModelConfig`` used as given — the way
+    a caller runs a published config with its depth cut, say.
+
     Returns (executor, trainer, reward_service); the executor is the
     virtual-clock controller, the threaded runtime or the process fleet
     depending on ``runtime`` — all expose
     .history/.clock/.effective_throughput()."""
     assert runtime in ("virtual", "threaded", "fleet"), runtime
     assert env in ("", "math", "code", "multiturn"), env
-    full_cfg = get_model_config(arch)
-    cfg = full_cfg
-    if scale == "laptop":
-        cfg = reduced(cfg)
-        cfg = dataclasses.replace(cfg, vocab_size=tokenizer.VOCAB_SIZE,
-                                  name=cfg.name + "-math")
+    if isinstance(arch, ModelConfig):
+        cfg = full_cfg = arch
+    else:
+        cfg = cli.model_config(arch, scale)
+        full_cfg = cli.model_config(arch, "pod")
     rl = RLConfig(batch_size=batch_size, answers_per_prompt=answers_per_prompt,
                   max_staleness=eta, decoupled_objective=decoupled,
                   interruptible=interruptible, lr=lr,
@@ -164,6 +169,10 @@ def run_training(arch: str = "areal-qwen-1.5b", *, steps: int = 25,
             seed=seed, prefill_chunk=prefill_chunk,
             continuation=continuation, **eng_extra))
         trainer = PPOTrainer(model, rl, params)
+        # hold no reference of our own: the initial tree is freed once the
+        # engine and the trainer have moved past it (1.7 GB of a 16 GB
+        # chip at areal-qwen-1.5b widths with 4 layers)
+        del params
     store = ParameterStore(ckpt_dir=ckpt_dir or None,
                            ckpt_every=10 if ckpt_dir else 0)
     if environment is None:
@@ -275,9 +284,8 @@ def run_training(arch: str = "areal-qwen-1.5b", *, steps: int = 25,
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="areal-qwen-1.5b")
+    cli.add_model_flags(ap)
     ap.add_argument("--steps", type=int, default=25)
-    ap.add_argument("--scale", default="laptop", choices=["laptop", "pod"])
     # engine / env / runtime flags are declared once, in launch/cli.py
     cli.add_engine_flags(ap, slots=16, seed=1)
     cli.add_env_flags(ap, default="", allow_legacy=True)
@@ -296,6 +304,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--no-final-eval", action="store_true")
     args = ap.parse_args()
+    cli.enable_compile_cache()
     cli.obs_setup(args, actor="train")
 
     t0 = time.time()

@@ -305,6 +305,7 @@ class Gateway:
                       preempted=rec.preempted, tokens=len(f.response))
         rec.sink.put(("end", {
             "rid": rec.rid, "tokens": list(f.response),
+            "logprobs": list(f.logprobs),
             "truncated": f.truncated, "turns": f.turns,
             "preempted": rec.preempted,
             "ttft": (rec.first_token_clock - rec.submit_clock

@@ -103,11 +103,23 @@ def test_flash_attention_backends_agree_random_shapes(b, s, hkv, hd, win,
 
 
 def test_backend_switch_roundtrip():
-    assert ops.get_backend() == "jnp"
-    ops.set_backend("pallas_interpret")
-    try:
-        q = jnp.ones((1, 8, 2, 16))
-        out = ops.flash_attention(q, q[:, :, :2], q[:, :, :2])
-        assert out.shape == q.shape
-    finally:
-        ops.set_backend("jnp")
+    """The backend is the platform's unless a scope says otherwise, and
+    the scope restores it on exit; interpret mode is per call only."""
+    assert ops.platform_backend() == "jnp"            # this suite runs on CPU
+    assert ops.current_backend() == "jnp"
+    q = jnp.ones((1, 8, 2, 16))
+
+    def prims(backend):
+        with ops.use_backend(backend):
+            jaxpr = jax.make_jaxpr(
+                lambda x: ops.flash_attention(x, x, x))(q)
+        return {e.primitive.name for e in jaxpr.jaxpr.eqns}
+
+    assert "pallas_call" in prims("pallas")
+    assert "pallas_call" not in prims("jnp")
+    assert ops.current_backend() == "jnp"
+    with pytest.raises(ValueError):
+        with ops.use_backend("pallas_interpret"):
+            pass
+    out = ops.flash_attention(q, q, q, backend="pallas_interpret")
+    assert out.shape == q.shape

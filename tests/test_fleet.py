@@ -188,6 +188,23 @@ def test_executor_protocol_covers_both_runtimes():
     assert isinstance(fleet, Executor)
 
 
+def test_fleet_refuses_to_contend_for_tpu_chips(monkeypatch):
+    """On a TPU the fleet fails at construction, naming the counts,
+    instead of starting workers that would hang on a held chip; workers
+    pinned to the CPU are allowed."""
+    import jax
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "local_devices", lambda: ["chip0"])
+    with pytest.raises(RuntimeError,
+                       match=r"3 chip-holding worker processes \(2 rollout "
+                             r"\+ 1 trainer\) on a host with 1 TPU chip"):
+        _fleet(_sched())
+    fleet = _fleet(_sched(), worker_env={"JAX_PLATFORMS": "cpu"})
+    assert fleet.worker_env == {"JAX_PLATFORMS": "cpu"}
+
+
 # ---------------------------------------------------------------------------
 # Fast: supervisor failure path (no processes — fakes)
 # ---------------------------------------------------------------------------
@@ -296,7 +313,10 @@ RUN_TIMEOUT = 240.0
 def test_fleet_sim_run_completes_and_counts():
     sched = _sched(eta=2, batch=8)
     cap = _capture(sched)
-    rt = _fleet(sched)
+    # 50 ms steps: the run outlasts the second rollout worker's start-up
+    # (a few seconds on a loaded host), which an instant run need not
+    rt = _fleet(sched, engine_factory_kwargs={"n_slots": 4,
+                                              "slow_step_s": 0.05})
     try:
         hist = rt.run(3, timeout=RUN_TIMEOUT)
     finally:
@@ -594,9 +614,12 @@ def test_fleet_kill_mid_weight_stream_never_applies_torn_version():
     bit-identical to the threaded reference — proof no torn partial
     version was ever decoded against.
 
-    stream_chunk_elems=64 makes v1's base-free full stream hundreds of
-    chunks long and stream_chunks_per_step=1 feeds them one per engine
-    step, so 'mid-stream' is a wide, reliably observable window."""
+    stream_chunk_elems=16 makes v1's base-free full stream thousands of
+    chunks long and stream_chunks_per_step=1 feeds them one per worker
+    loop, so 'mid-stream' outlasts a request's decode and the killer sees
+    it through the 50 ms heartbeats (at 64 elements per chunk a stream
+    fed while both workers sat idle ended between two beats in about
+    half the runs)."""
     ref = _threaded_reference()
     rl = _tiny_rl()
     sched = _math_sched(rl)
@@ -606,7 +629,7 @@ def test_fleet_kill_mid_weight_stream_never_applies_torn_version():
         engine_factory_kwargs={}, trainer_factory=tiny_trainer_factory,
         trainer_factory_kwargs={}, n_slots=2, rollout_workers=2,
         heartbeat_s=0.05, heartbeat_timeout=30.0,
-        weight_stream="delta", stream_chunk_elems=64,
+        weight_stream="delta", stream_chunk_elems=16,
         stream_chunks_per_step=1)
     killed = {}
 

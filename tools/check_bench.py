@@ -140,14 +140,15 @@ SPECS: Dict[str, List[Dict[str, Any]]] = {
         {"path": "spec.trajectories_identical", "equals": True},
         {"path": "spec.accepted_tokens_per_step", "min": 1.05},
         {"path": "spec.draft_acceptance_rate", "min": 0.5},
-        # per-family decode throughput sits inside its memory-bound
-        # roofline: gap in (0, 1] for every architecture family
+        # the gate runs CPU records: a CPU run measured no roofline
+        # share, and must say so instead of dividing by a chip's peak
+        {"path": "device.platform", "equals": "cpu"},
         {"path": "families.transformer.measured_over_roofline",
-         "min": 1e-9, "max": 1.0},
+         "equals": "not measured"},
         {"path": "families.rg-lru.measured_over_roofline",
-         "min": 1e-9, "max": 1.0},
+         "equals": "not measured"},
         {"path": "families.xlstm.measured_over_roofline",
-         "min": 1e-9, "max": 1.0},
+         "equals": "not measured"},
     ],
     "BENCH_serve_gateway.json": [
         # PR 9 acceptance: the Zipf session trace actually shares its
