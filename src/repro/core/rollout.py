@@ -53,6 +53,7 @@ Two prefill disciplines (``prefill_chunk``):
 """
 from __future__ import annotations
 
+import functools
 import threading
 import warnings
 from dataclasses import dataclass, field
@@ -329,7 +330,9 @@ class RolloutEngine:
     def _jit(self, fn):
         """``jax.jit`` of an engine step that honours ``place``: the mesh
         is read when the step is traced, and a placed engine's committed
-        state makes jit trace again."""
+        state makes jit trace again.  The program takes ``fn``'s name, so
+        each step is told apart in a profiler trace."""
+        @functools.wraps(fn)
         def step(*args):
             if self.mesh is None:
                 return fn(*args)
@@ -651,9 +654,14 @@ class RolloutEngine:
         by free pool blocks — prefix-shared blocks don't count).  Requests
         bounced on pool pressure are counted in ``deferred_last``."""
         self._assert_single_driver()
-        if trace.get().enabled and requests:
-            trace.instant("engine.admit", n=len(requests),
-                          rids=[r["rid"] for r in requests])
+        tr = trace.get()
+        if not tr.active:
+            return self._admit_impl(requests, clock)
+        with tr.span("rollout.admit", n=len(requests),
+                     rids=[r["rid"] for r in requests]):
+            return self._admit_impl(requests, clock)
+
+    def _admit_impl(self, requests: Sequence[Dict], clock: float) -> int:
         self.deferred_last = 0
         if self.prefill_chunk:
             return self._admit_chunked(requests, clock)
@@ -962,12 +970,12 @@ class RolloutEngine:
         skip — a "current" block observed by a later slot was fully
         written by an earlier, completed one."""
         tr = trace.get()
-        if not tr.enabled:
+        if not tr.active:
             return self._ingest_one_chunk_impl()
         i = self._ingest_queue[0]
         s = self.slots[i]
         b, e = s.chunk_plan[0]
-        with tr.span("engine.ingest", slot=i, rid=s.rid, begin=b, end=e):
+        with tr.span("rollout.ingest", slot=i, rid=s.rid, begin=b, end=e):
             return self._ingest_one_chunk_impl()
 
     def _ingest_one_chunk_impl(self) -> None:
@@ -1063,15 +1071,20 @@ class RolloutEngine:
         most one prefill span, then advance every slot whose history is
         fully in the cache.  Returns finished trajectories.  Monolithic
         engines (prefill_chunk=0) never have a span queued, so this is
-        exactly one decode step across all active slots."""
+        exactly one decode step across all active slots.
+
+        A decode step runs in three spans (DESIGN.md §Telemetry):
+        ``rollout.decode.prepare`` builds the inputs and dispatches,
+        ``rollout.decode.wait`` blocks on the sampled tokens, and
+        ``rollout.decode.finish`` does the per-slot bookkeeping."""
         tr = trace.get()
-        if not tr.enabled:                 # inert path: zero overhead
+        if not tr.active:                  # inert path: no clock, no args
             return self._step_impl()
-        with tr.span("engine.step", version=self.version,
+        with tr.span("rollout.step", version=self.version,
                      n_active=self.n_active):
             fin = self._step_impl()
         if fin:
-            tr.instant("engine.finished", n=len(fin),
+            tr.instant("rollout.finished", n=len(fin),
                        rids=[f.rid for f in fin])
         return fin
 
@@ -1090,9 +1103,37 @@ class RolloutEngine:
                 self._ingest_one_chunk()
         if self.spec_decode:
             return self._step_spec()
-        act = np.array([s.active and not s.ingesting for s in self.slots])
-        if not act.any():
-            return []
+        tr = trace.get()
+        with tr.span("rollout.decode.prepare"):
+            act = np.array([s.active and not s.ingesting for s in self.slots])
+            if not act.any():
+                return []
+            tok, lp = self._dispatch_decode(act)
+        with tr.span("rollout.decode.wait"):
+            tok = np.asarray(tok)
+            lp = np.asarray(lp)
+        with tr.span("rollout.decode.finish"):
+            finished: List[Finished] = []
+            for i, s in enumerate(self.slots):
+                if not act[i]:
+                    continue
+                # the pending token is now ingested; the new sample
+                # continues it
+                t_new, lp_new = int(tok[i]), float(lp[i])
+                s.response.append(t_new)
+                s.logprobs.append(lp_new)
+                s.versions.append(self.version)
+                s.pending = t_new
+                self.tokens_generated += 1
+                fin = self._maybe_finish(i, s)
+                if fin is not None:
+                    finished.append(fin)
+        return finished
+
+    def _dispatch_decode(self, act: np.ndarray):
+        """Build the decode inputs of the slots in ``act`` and dispatch
+        the decode; returns the sampled tokens and log-probs as device
+        arrays, not yet waited on."""
         if self._ingest_queue:
             self.decode_steps_during_prefill += 1
         pend = np.array([s.pending for s in self.slots], np.int32)
@@ -1124,23 +1165,7 @@ class RolloutEngine:
                 self.params, jnp.asarray(pend), self.cache, jnp.asarray(act),
                 rng, jnp.asarray(rids), jnp.asarray(draws))
             self.decode_dispatches += 1
-        tok = np.asarray(tok)
-        lp = np.asarray(lp)
-        finished: List[Finished] = []
-        for i, s in enumerate(self.slots):
-            if not act[i]:
-                continue
-            # the pending token is now ingested; the new sample continues it
-            t_new, lp_new = int(tok[i]), float(lp[i])
-            s.response.append(t_new)
-            s.logprobs.append(lp_new)
-            s.versions.append(self.version)
-            s.pending = t_new
-            self.tokens_generated += 1
-            fin = self._maybe_finish(i, s)
-            if fin is not None:
-                finished.append(fin)
-        return finished
+        return tok, lp
 
     def _maybe_finish(self, i: int, s: Slot) -> Optional[Finished]:
         """Shared end-of-trajectory handling for the plain and
@@ -1449,10 +1474,16 @@ class RolloutEngine:
         """Returns True if applied now; False if deferred (non-interruptible
         mode with in-flight requests — the Fig. 6b baseline)."""
         self._assert_single_driver()
-        trace.instant("engine.weight_flip", version=version,
-                      n_active=self.n_active,
-                      interruptible=interruptible,
-                      stream=self._in_stream_flip)
+        tr = trace.get()
+        if not tr.active:
+            return self._update_weights_impl(params, version, interruptible)
+        with tr.span("rollout.update_weights", version=version,
+                     n_active=self.n_active, interruptible=interruptible,
+                     stream=self._in_stream_flip):
+            return self._update_weights_impl(params, version, interruptible)
+
+    def _update_weights_impl(self, params, version: int,
+                             interruptible: bool) -> bool:
         if not self._in_stream_flip:
             self._invalidate_stream_decoder()
         if not interruptible and self.n_active > 0:

@@ -230,27 +230,29 @@ class ThreadedRuntime(SchedulerExecutorMixin):
             self.flightrec.record("pickup", version=version)
 
     def _rollout_tick(self) -> bool:
-        """One admission + decode round; returns True if any slot advanced."""
+        """One admission + decode round, in the span ``rollout.tick``;
+        returns True if any slot advanced."""
         eng = self.engine
-        self._maybe_pickup_weights()
-        eng.maybe_apply_pending()
-        if not eng.has_pending_weights:
-            reqs = self.sched.plan_admission(len(eng.free_slots()))
-            if reqs:
-                n = eng.admit(reqs, clock=self._now())
-                # the engine's own pool-pressure count drives requeue
-                # (free_slots() cannot see block headroom)
-                self.sched.admitted(reqs, n,
-                                    deferred=getattr(eng, "deferred_last", 0))
-        if eng.n_active == 0:
-            return False
-        n_act = eng.n_active
-        busy = self._train_busy           # sampled before the step
-        finished = eng.step()
-        if busy:
-            self.tokens_during_train += n_act
-        self.sched.collect(finished, finish_time=self._now())
-        return True
+        with trace.span("rollout.tick"):
+            self._maybe_pickup_weights()
+            eng.maybe_apply_pending()
+            if not eng.has_pending_weights:
+                reqs = self.sched.plan_admission(len(eng.free_slots()))
+                if reqs:
+                    n = eng.admit(reqs, clock=self._now())
+                    # the engine's own pool-pressure count drives requeue
+                    # (free_slots() cannot see block headroom)
+                    self.sched.admitted(
+                        reqs, n, deferred=getattr(eng, "deferred_last", 0))
+            if eng.n_active == 0:
+                return False
+            n_act = eng.n_active
+            busy = self._train_busy           # sampled before the step
+            finished = eng.step()
+            if busy:
+                self.tokens_during_train += n_act
+            self.sched.collect(finished, finish_time=self._now())
+            return True
 
     def _rollout_loop(self) -> None:
         try:

@@ -68,14 +68,19 @@ class PPOTrainer:
         # the params are not donated, since the published versions and the
         # engine may still read them.  At 420 M f32 params (areal-qwen-1.5b
         # widths, 4 layers) this keeps ~5 GB of copies off one 16 GB chip.
-        self._jit_accumulate = jax.jit(
-            lambda acc, g: jax.tree.map(jnp.add, acc, g), donate_argnums=0)
-        self._jit_apply = jax.jit(
-            lambda p, g, s, n: optim.apply_updates(
-                self.adam, p, jax.tree.map(lambda x: x / n, g), s),
-            donate_argnums=(1, 2))
+        self._jit_accumulate = jax.jit(self._accumulate_fn, donate_argnums=0)
+        self._jit_apply = jax.jit(self._apply_fn, donate_argnums=(1, 2))
 
     # ---- jit bodies -------------------------------------------------------
+    @staticmethod
+    def _accumulate_fn(acc, g):
+        return jax.tree.map(jnp.add, acc, g)
+
+    def _apply_fn(self, params, grad_sum, opt_state, n):
+        return optim.apply_updates(
+            self.adam, params, jax.tree.map(lambda x: x / n, grad_sum),
+            opt_state)
+
     def _forward_logprobs(self, params, batch):
         # imported here, not at the top: the kernels load Pallas, which
         # processes that import repro.core without tracing a model (fleet

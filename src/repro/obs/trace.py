@@ -15,12 +15,18 @@ deterministic runs, the gateway's tick counter for offline serving —
 so every executor traces in its own time base and the exported
 timeline is internally consistent rather than wall-approximate.
 
+Profiler sink: while a JAX profiler session is collecting
+(``TraceAnnotation.is_enabled()``), every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name and arguments, so it
+lands on the device trace's clock beside the device operations, whether
+or not this tracer is enabled.  Instants and counters stay in memory.
+
 Disabled-mode guarantee (DESIGN.md §Disabled-mode guarantee): when
-``enabled`` is False, ``span()`` returns one shared no-op context
-manager and ``instant()``/``counter()`` return before touching the
-clock or any buffer.  The tracer allocates nothing, reads no clock,
-and perturbs no RNG — which is what keeps trajectory and StepLog
-goldens bit-for-bit identical with tracing off.
+``enabled`` is False and no profiler session collects, ``span()``
+returns one shared no-op context manager and ``instant()``/``counter()``
+return before touching the clock or any buffer.  The tracer allocates
+nothing, reads no clock, and perturbs no RNG — which is what keeps
+trajectory and StepLog goldens bit-for-bit identical with tracing off.
 
 Span events are appended at *enter* time (their duration is patched in
 at exit), so each thread's buffer is naturally monotone in start
@@ -30,13 +36,18 @@ from __future__ import annotations
 
 import threading
 from time import perf_counter
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "get", "configure", "span", "instant", "counter"]
 
 # Event record layout (a plain list — mutated in place at span exit):
 #   [ph, name, ts, dur_or_value, actor, track, args]
 # ph: "X" complete span | "i" instant | "C" counter sample.
+
+# True while a JAX profiler session collects
+_profiling: Callable[[], bool] = TraceAnnotation.is_enabled
 
 
 class _NullSpan:
@@ -54,18 +65,25 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Open span: appended to the buffer on enter, patched on exit."""
-    __slots__ = ("_ev", "_clock")
+    """Open span: appended to the buffer on enter, patched on exit; it
+    also holds the profiler annotation while a session collects."""
+    __slots__ = ("_ev", "_clock", "_ann")
 
-    def __init__(self, ev: list, clock: Callable[[], float]):
+    def __init__(self, ev: list, clock: Callable[[], float],
+                 ann: Optional[TraceAnnotation]):
         self._ev = ev
         self._clock = clock
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._ev[3] = self._clock() - self._ev[2]
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -122,15 +140,24 @@ class Tracer:
                 self._buffers.append(buf)
         return buf
 
+    @property
+    def active(self) -> bool:
+        """True when ``span()`` records into either sink: callers test it
+        before building span arguments."""
+        return self.enabled or _profiling()
+
     def span(self, name: str, **args: Any):
-        """Context manager timing a region.  Free when disabled."""
+        """Context manager timing a region, in this tracer's buffers when
+        enabled and in the profiler's trace while a session collects.
+        Free when neither is on."""
         if not self.enabled:
-            return _NULL_SPAN
+            return TraceAnnotation(name, **args) if _profiling() else _NULL_SPAN
         buf = self._buf()
         ev = ["X", name, self._clock(), 0.0, self._actor,
               self._local.track, args or None]
         buf.append(ev)
-        return _Span(ev, self._clock)
+        ann = TraceAnnotation(name, **args) if _profiling() else None
+        return _Span(ev, self._clock, ann)
 
     def instant(self, name: str, **args: Any) -> None:
         """Point event (admission, flip fence, preemption …)."""
@@ -185,8 +212,3 @@ def instant(name: str, **args: Any) -> None:
 
 def counter(name: str, value: float) -> None:
     _GLOBAL.counter(name, value)
-
-
-def snapshot_args() -> Dict[str, Any]:
-    """Debug helper: current global tracer configuration."""
-    return {"enabled": _GLOBAL.enabled, "actor": _GLOBAL._actor}

@@ -7,13 +7,22 @@ this suite uses and draws deterministic pseudo-random examples — the
 first example per strategy is the minimal/boundary draw, mirroring
 hypothesis's shrink-toward-minimal bias.  With ``pip install -e .[test]``
 the real hypothesis is present and this module does nothing.
+
+It also provides the ``profiled`` fixture: a JAX profiler session and
+the program's spans read back from its trace.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import glob
+import os
 import random
 import sys
 import types
+import warnings
+
+import pytest
 
 try:
     import hypothesis  # noqa: F401  (real package wins when installed)
@@ -107,3 +116,47 @@ except ImportError:
     _hyp.__is_repro_stub__ = True
     sys.modules["hypothesis"] = _hyp
     sys.modules["hypothesis.strategies"] = _st
+
+
+class Profiled:
+    """``with profiled.session(): ...`` collects a JAX profiler trace;
+    ``profiled.spans(prefix)`` then reads back the host annotations whose
+    names start with ``prefix`` as dicts (line, name, start, end, stats),
+    in start order; spans of one thread share a ``line``."""
+
+    def __init__(self, log_dir: str):
+        self.log_dir = log_dir
+
+    @contextlib.contextmanager
+    def session(self):
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        with jax.profiler.trace(self.log_dir, profiler_options=opts):
+            yield
+
+    def spans(self, prefix: str):
+        from jax.profiler import ProfileData
+        path, = glob.glob(os.path.join(self.log_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+        out = []
+        # jaxlib's nanobind stats type warns when first built
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)
+            for plane in ProfileData.from_file(path).planes:
+                if not plane.name.startswith("/host:"):
+                    continue
+                # a line is a thread's; threads may share a line name
+                for k, line in enumerate(plane.lines):
+                    out.extend({"line": (plane.name, k), "name": e.name,
+                                "start": e.start_ns,
+                                "end": e.start_ns + e.duration_ns,
+                                "stats": dict(e.stats)}
+                               for e in line.events
+                               if e.name.startswith(prefix))
+        return sorted(out, key=lambda e: (e["start"], -e["end"]))
+
+
+@pytest.fixture
+def profiled(tmp_path):
+    return Profiled(str(tmp_path / "profile"))

@@ -45,10 +45,52 @@ def test_disabled_tracer_is_inert():
     assert tr.drain() == []
 
 
+def test_disabled_tracer_spans_reach_the_profiler(profiled):
+    """DESIGN.md §Telemetry: while a profiler session collects, a
+    DISABLED tracer's spans are profiler annotations under their plain
+    names (arguments ride as stats), nested as entered, and its own
+    clock is still never read."""
+    def poison():
+        raise AssertionError("disabled tracer read the clock")
+
+    tr = Tracer(enabled=False, clock=poison)
+    with profiled.session():
+        assert tr.active
+        with tr.span("rollout.step", version=3):
+            with tr.span("rollout.decode.prepare"):
+                pass
+            with tr.span("rollout.decode.wait"):
+                pass
+    assert not tr.active and tr.span("x") is _NULL_SPAN
+    assert tr.event_count() == 0
+    step, prep, wait = profiled.spans("rollout.")
+    assert [step["name"], prep["name"], wait["name"]] == [
+        "rollout.step", "rollout.decode.prepare", "rollout.decode.wait"]
+    assert step["stats"] == {"version": 3}
+    assert len({step["line"], prep["line"], wait["line"]}) == 1
+    assert step["start"] <= prep["start"] <= prep["end"] <= wait["start"]
+    assert wait["end"] <= step["end"]
+
+
+def test_enabled_tracer_writes_both_sinks(profiled):
+    tr = Tracer(enabled=True, clock=_fake_clock([1.0, 2.0, 4.0, 7.0]))
+    with profiled.session():
+        with tr.span("trainer.train_step", n=2):
+            with tr.span("trainer.inner"):
+                pass
+    assert [(e[1], e[2], e[3]) for e in tr.drain()] == [
+        ("trainer.train_step", 1.0, 6.0), ("trainer.inner", 2.0, 2.0)]
+    outer, inner = profiled.spans("trainer.")
+    assert (outer["name"], inner["name"]) == ("trainer.train_step",
+                                              "trainer.inner")
+    assert outer["stats"] == {"n": 2}
+    assert outer["start"] <= inner["start"] <= inner["end"] <= outer["end"]
+
+
 def test_global_helpers_follow_configure():
     trace.configure(enabled=False)
     assert trace.span("x") is _NULL_SPAN
-    assert trace.snapshot_args()["enabled"] is False
+    assert trace.get().active is False
 
 
 # ---------------------------------------------------------------------------
@@ -107,8 +149,8 @@ def _sample_events():
     tr = Tracer(enabled=True,
                 clock=_fake_clock([0.1, 0.2, 0.3, 0.4, 0.5]), actor="a")
     tr.set_track("rollout")
-    with tr.span("engine.step", version=1):
-        tr.instant("engine.admit", n=2)
+    with tr.span("rollout.step", version=1):
+        tr.instant("rollout.finished", n=2)
     tr.counter("staleness", 1.5)
     tr.set_actor("b")
     tr.instant("other-proc")
@@ -120,7 +162,7 @@ def test_export_is_valid_and_typed():
     assert trace_check.validate(doc) == []
     evs = doc["traceEvents"]
     x = next(e for e in evs if e["ph"] == "X")
-    assert x["name"] == "engine.step"
+    assert x["name"] == "rollout.step"
     assert x["ts"] == pytest.approx(0.1 * 1e6)    # seconds -> µs
     assert x["dur"] == pytest.approx(0.2 * 1e6)   # exit 0.3 - enter 0.1
     c = next(e for e in evs if e["ph"] == "C")

@@ -32,7 +32,10 @@ def _reqs(n, start=0):
              "prompt": [1, 4 + i, 5, 6], "answer": None} for i in range(n)]
 
 
-def _run_to_completion(engine, reqs, interrupt_at=()):
+def _run_to_completion(engine, reqs, interrupt_at=(), new_params=None):
+    """Drive ``reqs`` to completion, interrupting before each step in
+    ``interrupt_at``: with the same weights, or with ``new_params`` under
+    the next version."""
     done = {}
     pending = list(reqs)
     step = 0
@@ -40,7 +43,10 @@ def _run_to_completion(engine, reqs, interrupt_at=()):
         n = engine.admit(pending)
         pending = pending[n:]
         if step in interrupt_at:
-            engine.update_weights(engine.params, engine.version)  # same weights
+            if new_params is None:                 # same weights
+                engine.update_weights(engine.params, engine.version)
+            else:
+                engine.update_weights(new_params, engine.version + 1)
         for f in engine.step():
             done[f.rid] = f
         step += 1
@@ -422,6 +428,54 @@ def test_chunked_engine_progresses_under_per_step_weight_refresh():
     assert e.reprefill_tokens > 0
 
 
+def _inside(inner, outer):
+    return (inner["line"] == outer["line"]
+            and outer["start"] <= inner["start"] <= inner["end"] <= outer["end"])
+
+
+def test_engine_phase_spans_under_profiler(profiled):
+    """DESIGN.md §Telemetry: under a profiler session a paged, chunked
+    engine writes its phases into the trace.  Every decode step nests
+    rollout.decode.prepare, .wait and .finish inside rollout.step, in
+    that order; the steps after an interrupt carry its re-prefill as
+    rollout.ingest; and the sampled tokens and log-probs are bitwise
+    those of the same run with no profiler."""
+    cfg = _tiny()
+    kw = dict(seed=3, cache="paged", block_size=4, prefill_chunk=3)
+    _, params, e_off = _engine(cfg, **kw)
+    new = jax.tree.map(lambda x: x * 1.01, params)
+    d_off = _run_to_completion(e_off, _reqs(4), (2,), new)
+    _, _, e_on = _engine(cfg, **kw)
+    with profiled.session():
+        d_on = _run_to_completion(e_on, _reqs(4), (2,), new)
+    assert e_on.interruptions == 1 and e_on.reprefill_tokens > 0
+    assert {r: (f.response, f.logprobs) for r, f in d_on.items()} == {
+        r: (f.response, f.logprobs) for r, f in d_off.items()}
+
+    spans = profiled.spans("rollout.")
+    steps = [x for x in spans if x["name"] == "rollout.step"]
+    flip, = [x for x in spans if x["name"] == "rollout.update_weights"]
+    assert flip["stats"]["version"] == 1
+    assert any(x["name"] == "rollout.admit" for x in spans)
+    n_decode = 0
+    for st in steps:
+        inner = [x for x in spans if x is not st and _inside(x, st)]
+        names = [x["name"] for x in inner if x["name"] != "rollout.ingest"]
+        if "rollout.decode.wait" in names:
+            n_decode += 1
+            assert names == ["rollout.decode.prepare", "rollout.decode.wait",
+                             "rollout.decode.finish"]
+        else:
+            assert names in ([], ["rollout.decode.prepare"])
+    assert n_decode == e_on.decode_dispatches
+    ingests = [x for x in spans if x["name"] == "rollout.ingest"]
+    assert all(any(_inside(x, st) for st in steps) for x in ingests)
+    # every slot is re-queued: the first step after the interrupt
+    # re-ingests the head slot's history in several chunks
+    after = next(st for st in steps if st["start"] >= flip["end"])
+    assert sum(_inside(x, after) for x in ingests) >= 2
+
+
 def test_chunked_rng_scheme_is_enforced():
     cfg = _tiny()
     with pytest.raises(ValueError, match="rng='request'"):
@@ -472,10 +526,7 @@ def test_scheduler_starves_stream_pulls_on_engine_deferral():
     assert [r["rid"] for r in fresh] == [4, 5]
 
 
-def test_threaded_runtime_with_chunked_engine():
-    """The threaded runtime over a REAL chunked engine: the run
-    completes, and decode steps demonstrably occur while the ingest
-    queue is non-empty (generation never waits for a whole prefill)."""
+def _threaded_chunked_runtime():
     from repro.configs.base import RLConfig
     from repro.core import AsyncScheduler, PPOTrainer, ThreadedRuntime
     from repro.data.dataset import PromptStream
@@ -494,11 +545,42 @@ def test_threaded_runtime_with_chunked_engine():
     sched = AsyncScheduler(
         prompt_stream=PromptStream(seed=2, answers_per_prompt=2,
                                    max_operand=9), rl=rl)
-    rt = ThreadedRuntime(engine=engine, trainer=trainer, scheduler=sched)
+    return ThreadedRuntime(engine=engine, trainer=trainer, scheduler=sched)
+
+
+def test_threaded_runtime_with_chunked_engine():
+    """The threaded runtime over a REAL chunked engine: the run
+    completes, and decode steps demonstrably occur while the ingest
+    queue is non-empty (generation never waits for a whole prefill)."""
+    rt = _threaded_chunked_runtime()
+    engine = rt.engine
     hist = rt.run(2, timeout=300)
     assert [h.version for h in hist] == [1, 2]
     assert engine.tokens_generated > 0
     assert engine.stats()["decode_steps_during_prefill"] > 0
+
+
+def test_threaded_runtime_spans_under_profiler(profiled):
+    """Under a profiler session the rollout thread's ticks hold its
+    admissions, interrupts and engine steps, and the trainer's steps lie
+    on the trainer thread's line (DESIGN.md §Telemetry).  The final
+    pickup after the run is the calling thread's, outside any tick."""
+    rt = _threaded_chunked_runtime()
+    with profiled.session():
+        rt.run(2, timeout=300)
+    spans = profiled.spans("rollout.") + profiled.spans("trainer.")
+    by = {}
+    for x in spans:
+        by.setdefault(x["name"], []).append(x)
+    assert len(by["trainer.train_step"]) == 2
+    assert by["rollout.update_weights"]
+    ticks = by["rollout.tick"]
+    lines = {x["line"] for x in ticks}
+    for name in ("rollout.admit", "rollout.update_weights", "rollout.step"):
+        mine = [x for x in by[name] if x["line"] in lines]
+        assert mine and all(any(_inside(x, t) for t in ticks)
+                            for x in mine), name
+    assert lines.isdisjoint(x["line"] for x in by["trainer.train_step"])
 
 
 def test_single_driver_contract_enforced():
