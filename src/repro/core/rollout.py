@@ -70,6 +70,7 @@ from repro.core.batching import (BlockAllocator, plan_prefill_chunks,
                                  prefix_block_hashes)
 from repro.core.config import EngineConfig
 from repro.data import tokenizer
+from repro.models import model as model_mod
 from repro.obs import trace
 
 
@@ -169,7 +170,6 @@ class RolloutEngine:
         self.model = model
         self.cfg: ModelConfig = model.cfg
         self.engine_config = cfg
-        self.params = params
         self.version = cfg.version
         self.n_slots = cfg.n_slots
         self.prompt_len = cfg.prompt_len
@@ -210,6 +210,12 @@ class RolloutEngine:
         # fused exclusivity, spec-forces-greedy, fused-needs-paged) are
         # validated by EngineConfig — only MODEL-capability checks remain
         self.fused_decode = fused_decode
+        # the weights, and the copy the engine's programs read
+        # (DESIGN.md §Rollout run weights)
+        self.run_weight_casts = 0
+        self.run_weight_dtype: Optional[str] = None
+        self._params = self.run_params = None
+        self.params = params
         self.spec_decode = int(spec_decode)
         if self.spec_decode:
             chunk_attr = ("prefill_chunk_paged" if cache == "paged"
@@ -314,6 +320,32 @@ class RolloutEngine:
                 self._jit_spec_commit = self._jit(self._spec_commit_fn)
         if self.prefill_chunk:
             self._jit_reset = self._jit(self.model.reset_slot_rows)
+
+    # ---- weights ----------------------------------------------------------
+    @property
+    def params(self):
+        """The weights as given (the f32 master weights): what
+        publication, ``host_weights`` and the delta codecs read."""
+        return self._params
+
+    @params.setter
+    def params(self, params) -> None:
+        """Every change of ``params`` rebuilds ``run_params``, the tree
+        the engine's programs read (DESIGN.md §Rollout run weights): the
+        dot weights cast once to ``model.run_weight_dtype()`` where that
+        rule engages, else ``params`` itself.  The old copy is released
+        before the cast, so at most one copy is held."""
+        if params is self._params:
+            return
+        self.run_params = None
+        self._params = params
+        dtype = model_mod.run_weight_dtype()
+        run = params if dtype is None else model_mod.run_weights(
+            params, dtype, fused_tail=self.fused_decode == "fused")
+        if run is not params:
+            self.run_weight_casts += 1
+        self.run_weight_dtype = None if run is params else jnp.dtype(dtype).name
+        self.run_params = run
 
     # ---- placement --------------------------------------------------------
     def place(self, mesh) -> None:
@@ -620,6 +652,8 @@ class RolloutEngine:
             "spec_rounds": self.spec_rounds,
             "draft_acceptance_rate": self.draft_acceptance_rate,
             "accepted_tokens_per_step": self.accepted_tokens_per_step,
+            "run_weight_casts": self.run_weight_casts,
+            "run_weight_dtype": self.run_weight_dtype,
             **self.stream_stats(),
         }
 
@@ -684,7 +718,7 @@ class RolloutEngine:
             slot_ids[j] = free[j]
         lens = np.maximum(lens, 1)
         tok0, lp0, sub_cache = self._jit_prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
+            self.run_params, jnp.asarray(toks), jnp.asarray(lens),
             self._next_rng(), jnp.asarray(rids))
         self.cache = self._jit_insert(self.cache, sub_cache, jnp.asarray(slot_ids))
         self._activate_slots(take, free, lens, tok0, lp0, clock)
@@ -791,7 +825,7 @@ class RolloutEngine:
             return 0
         self._tables_dev = None
         tok0, lp0, self.cache = self._jit_prefill_paged(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
+            self.run_params, jnp.asarray(toks), jnp.asarray(lens),
             jnp.asarray(dest), jnp.asarray(slot_ids), self.cache,
             self._next_rng(), jnp.asarray(rids))
         self._activate_slots(take, free, lens, tok0, lp0, clock)
@@ -1016,23 +1050,23 @@ class RolloutEngine:
                     self.allocator.set_version(b, self.version)
             if completes:
                 tok0, lp0, self.cache = self._jit_chunk_paged(
-                    self.params, jnp.asarray(toks), self.cache,
+                    self.run_params, jnp.asarray(toks), self.cache,
                     jnp.asarray(self.tables[i:i + 1]), jnp.asarray(dest),
                     sids, start, length, rids)
             else:
                 self.cache = self._jit_chunk_paged_quiet(
-                    self.params, jnp.asarray(toks), self.cache,
+                    self.run_params, jnp.asarray(toks), self.cache,
                     jnp.asarray(self.tables[i:i + 1]), jnp.asarray(dest),
                     sids, start, length)
         else:
             written = len(span)
             if completes:
                 tok0, lp0, self.cache = self._jit_chunk(
-                    self.params, jnp.asarray(toks), self.cache,
+                    self.run_params, jnp.asarray(toks), self.cache,
                     sids, start, length, rids)
             else:
                 self.cache = self._jit_chunk_quiet(
-                    self.params, jnp.asarray(toks), self.cache,
+                    self.run_params, jnp.asarray(toks), self.cache,
                     sids, start, length)
         s.ingested = end
         # accounting keys on the ingest kind, not on response presence: a
@@ -1149,20 +1183,20 @@ class RolloutEngine:
                 # measurement baseline: decode and sampling are separate
                 # dispatches (DESIGN.md §Fused decode tail)
                 logits, self.cache = self._jit_decode_logits(
-                    self.params, jnp.asarray(pend), self.cache,
+                    self.run_params, jnp.asarray(pend), self.cache,
                     self._tables_dev, jnp.asarray(act))
                 tok, lp = self._jit_sample(logits, rng, jnp.asarray(rids),
                                            jnp.asarray(draws))
                 self.decode_dispatches += 2
             else:
                 tok, lp, self.cache = self._jit_decode_paged(
-                    self.params, jnp.asarray(pend), self.cache,
+                    self.run_params, jnp.asarray(pend), self.cache,
                     self._tables_dev, jnp.asarray(act), rng,
                     jnp.asarray(rids), jnp.asarray(draws))
                 self.decode_dispatches += 1
         else:
             tok, lp, self.cache = self._jit_decode(
-                self.params, jnp.asarray(pend), self.cache, jnp.asarray(act),
+                self.run_params, jnp.asarray(pend), self.cache, jnp.asarray(act),
                 rng, jnp.asarray(rids), jnp.asarray(draws))
             self.decode_dispatches += 1
         return tok, lp
@@ -1234,11 +1268,11 @@ class RolloutEngine:
         if self.cache_mode == "paged":
             if self._tables_dev is None:
                 self._tables_dev = jnp.asarray(self.tables)
-            drafts = self._jit_spec_draft(self.params, jnp.asarray(pend),
+            drafts = self._jit_spec_draft(self.run_params, jnp.asarray(pend),
                                           self.cache, self._tables_dev,
                                           jnp.asarray(act))
         else:
-            drafts = self._jit_spec_draft(self.params, jnp.asarray(pend),
+            drafts = self._jit_spec_draft(self.run_params, jnp.asarray(pend),
                                           self.cache, jnp.asarray(act))
         self.decode_dispatches += 1
         self.drafted_tokens += (k - 1) * int(act.sum())
@@ -1267,12 +1301,12 @@ class RolloutEngine:
             if self._tables_dev is None:
                 self._tables_dev = jnp.asarray(self.tables)
             gtok, glp = self._jit_spec_verify(
-                self.params, toks_d, self.cache, self._tables_dev,
+                self.run_params, toks_d, self.cache, self._tables_dev,
                 jnp.asarray(self._span_dest(start, length)), sids_d,
                 start_d, jnp.asarray(length))
         else:
             gtok, glp = self._jit_spec_verify(
-                self.params, toks_d, self.cache, sids_d, start_d,
+                self.run_params, toks_d, self.cache, sids_d, start_d,
                 jnp.asarray(length))
         self.decode_dispatches += 1
         gtok = np.asarray(gtok)                       # (n_slots, k)
@@ -1296,12 +1330,12 @@ class RolloutEngine:
         length_c = np.where(members, acc, 0).astype(np.int32)
         if self.cache_mode == "paged":
             self.cache = self._jit_spec_commit(
-                self.params, toks_d, self.cache, self._tables_dev,
+                self.run_params, toks_d, self.cache, self._tables_dev,
                 jnp.asarray(self._span_dest(start, length_c)), sids_d,
                 start_d, jnp.asarray(length_c))
         else:
             self.cache = self._jit_spec_commit(
-                self.params, toks_d, self.cache, sids_d, start_d,
+                self.run_params, toks_d, self.cache, sids_d, start_d,
                 jnp.asarray(length_c))
         self.decode_dispatches += 1
         self.spec_rounds += 1
@@ -1592,7 +1626,7 @@ class RolloutEngine:
         # keeps the decode RNG stream untouched, so an interruption with
         # unchanged weights is bit-identical to no interruption (Prop. 1 test).
         _, _, sub_cache = self._jit_prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
+            self.run_params, jnp.asarray(toks), jnp.asarray(lens),
             jax.random.key(0), jnp.zeros((g,), jnp.int32))
         self.cache = self._jit_insert(self.cache, sub_cache,
                                       jnp.asarray(slot_ids))
@@ -1640,6 +1674,6 @@ class RolloutEngine:
                 self.allocator.register(h, int(self.tables[i, e]))
         lens = np.maximum(lens, 1)
         _, _, self.cache = self._jit_prefill_paged(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
+            self.run_params, jnp.asarray(toks), jnp.asarray(lens),
             jnp.asarray(dest), jnp.asarray(slot_ids), self.cache,
             jax.random.key(0), jnp.zeros((g,), jnp.int32))

@@ -62,7 +62,7 @@ def batch_spec(mesh, global_batch: int):
     return axes if len(axes) > 1 else axes[0]
 
 
-def _path_names(path) -> Tuple[str, ...]:
+def path_names(path) -> Tuple[str, ...]:
     names = []
     for key in path:
         if hasattr(key, "key"):
@@ -157,7 +157,7 @@ def param_spec(cfg: ModelConfig, mesh, path, leaf, *, fsdp: bool = False,
     ``path`` is a jax key path (tree_map_with_path); only the key *names*
     are consulted.  ``leaf`` needs only a ``.shape``.
     """
-    names = _path_names(path)
+    names = path_names(path)
     shape = tuple(leaf.shape)
     rule = _base_rule(cfg, mesh, names, shape)
     rank = len(shape)
@@ -221,7 +221,7 @@ def make_cache_specs(cfg: ModelConfig, mesh, cache):
     msize = _model_size(mesh)
 
     def spec(path, leaf):
-        names = _path_names(path)
+        names = path_names(path)
         shape = tuple(leaf.shape)
         rank = len(shape)
         if names[-1] in ("k_pool", "v_pool"):

@@ -29,7 +29,16 @@ def matmul(x, w):
     # f32-dot lowering (pre-SPMD), not from autodiff promotion; on TPU
     # the gathers are bf16.  Collective bytes for bf16 programs in the
     # CPU dry-run are therefore a <=2x upper bound.
+    if _narrower(w, x):
+        # a run weight (DESIGN.md §Rollout run weights): meet it in its
+        # dtype, or JAX promotes it back and XLA converts it again
+        return jnp.matmul(x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32).astype(x.dtype)
     return jnp.matmul(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _narrower(w, x) -> bool:
+    return jnp.dtype(w.dtype).itemsize < jnp.dtype(x.dtype).itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -217,4 +226,6 @@ def unembed_apply(params_embed, params_head, x, tie: bool):
         w = params_embed["table"].T
     else:
         w = params_head["w"]
+    if _narrower(w, x):
+        x = x.astype(w.dtype)              # as in ``matmul``
     return jnp.matmul(x, w, preferred_element_type=jnp.float32)

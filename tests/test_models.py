@@ -142,3 +142,29 @@ def test_param_counts_near_nameplate():
     for arch, t in targets.items():
         n = get_model_config(arch).param_count()
         assert 0.75 * t < n < 1.35 * t, f"{arch}: {n:.2e} vs {t:.2e}"
+
+
+@pytest.mark.parametrize("fn", ["matmul", "unembed"])
+def test_narrower_weight_is_met_in_its_dtype(fn):
+    """A bf16 run weight against f32 activations (DESIGN.md §Rollout run
+    weights): the activations are cast to bf16 and the weight is never
+    converted back to f32, so XLA has nothing to re-round; equal dtypes
+    keep the plain dot."""
+    from repro.models import layers
+
+    x = jax.random.normal(jax.random.key(0), (3, 16), jnp.float32)
+    w = jax.random.normal(jax.random.key(1), (16, 8), jnp.float32)
+    call = {"matmul": layers.matmul,
+            "unembed": lambda x, w: layers.unembed_apply(
+                {}, {"w": w}, x, tie=False)}[fn]
+    w16 = w.astype(jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(call)(x, w16).jaxpr
+    dot = next(e for e in jaxpr.eqns if e.primitive.name == "dot_general")
+    assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
+    assert not any(e.primitive.name == "convert_element_type"
+                   and e.invars[0] is jaxpr.invars[1] for e in jaxpr.eqns)
+    want = jnp.matmul(x.astype(jnp.bfloat16), w16,
+                      preferred_element_type=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(call(x, w16)), np.asarray(want))
+    same = jax.make_jaxpr(call)(x, w).jaxpr
+    assert [e.primitive.name for e in same.eqns][0] == "dot_general"

@@ -912,3 +912,140 @@ def test_spec_stats_surface():
     assert st["drafted_tokens"] > 0
     assert st["accepted_tokens"] == eng.tokens_generated
     assert st["accepted_tokens_per_step"] > 0.0
+
+
+# ---- rollout run weights (DESIGN.md §Rollout run weights) -----------------
+
+_CAST = {("attn", "wq"), ("attn", "wk"), ("attn", "wv"), ("attn", "wo"),
+         ("mlp", "w_up"), ("mlp", "w_gate"), ("mlp", "w_down"), ("head", "w")}
+
+
+def _check_run_tree(eng, params, cast=_CAST):
+    """``eng.run_params`` holds ``params``' dot weights in bf16 with the
+    values of ``astype(bf16)``, and every other leaf as the same array."""
+    import jax.numpy as jnp
+    from repro.dist.sharding import path_names
+
+    assert eng.params is params
+    seen = set()
+    for (path, run), (_, src) in zip(
+            jax.tree_util.tree_flatten_with_path(eng.run_params)[0],
+            jax.tree_util.tree_flatten_with_path(params)[0]):
+        key = path_names(path)[-2:]
+        if key in cast:
+            seen.add(key)
+            assert run.dtype == jnp.bfloat16, key
+            np.testing.assert_array_equal(
+                np.asarray(run), np.asarray(src.astype(jnp.bfloat16)))
+        else:
+            assert run is src, key
+            assert run.dtype == jnp.float32, key
+    assert seen == cast
+    assert eng.stats()["run_weight_dtype"] == "bfloat16"
+
+
+def test_run_weights_rebuilt_once_per_applied_version():
+    """With the rule forced on, the engine keeps the f32 tree it was
+    given as ``params`` and casts the dot weights once each time the
+    weights change: at construction, ``place``, ``update_weights``, a
+    deferred update applied by ``maybe_apply_pending`` and a streamed
+    flip.  An update deferred behind in-flight requests casts nothing,
+    and the engine still generates from the cast copy."""
+    from jax.sharding import Mesh
+
+    from repro.core.weights import encode_stream
+    from repro.launch.disaggregated import host_weights
+    from repro.models import model as model_mod
+
+    cfg = _tiny()
+    model = build_model(cfg, remat=False)
+    params = model.init(jax.random.key(7))
+    bump = lambda p, k: jax.tree.map(lambda x: x + 0.01 * k, p)  # noqa: E731
+    with model_mod.use_run_weights(True):
+        eng = RolloutEngine(model, params, cfg=EngineConfig(
+            n_slots=4, prompt_len=8, max_gen_len=6, cache="paged",
+            block_size=4))
+        casts = lambda: eng.stats()["run_weight_casts"]    # noqa: E731
+        assert casts() == 1
+        _check_run_tree(eng, params)
+
+        eng.place(Mesh(np.array(jax.devices()[:1]), ("x",)))
+        assert casts() == 2
+        _check_run_tree(eng, eng.params)
+
+        p1 = bump(params, 1)
+        assert eng.update_weights(p1, 1)
+        assert casts() == 3
+        _check_run_tree(eng, p1)
+        eng.update_weights(p1, 1)                  # the same tree again
+        assert casts() == 3
+
+        assert eng.admit(_reqs(2)) == 2
+        p2 = bump(params, 2)
+        assert not eng.update_weights(p2, 2, interruptible=False)
+        assert casts() == 3                        # deferred: no cast
+        _check_run_tree(eng, p1)
+        done = {}
+        while len(done) < 2:
+            done.update({f.rid: f for f in eng.step()})
+        assert all(f.versions == [1] * len(f.response) for f in done.values())
+        assert eng.maybe_apply_pending()
+        assert casts() == 4 and eng.version == 2
+        _check_run_tree(eng, p2)
+
+        p3 = bump(params, 3)
+        stream = encode_stream(host_weights(p3), version=3,
+                               base=host_weights(p2), base_version=2,
+                               encoding="delta", chunk_elems=64)
+        assert [eng.feed_weight_message(m) for m in stream][-1]
+        assert casts() == 5 and eng.version == 3
+        _check_run_tree(eng, eng.params)
+        np.testing.assert_array_equal(
+            np.asarray(eng.params["head"]["w"]), np.asarray(p3["head"]["w"]))
+
+
+def test_run_weights_fused_tail_keeps_wo():
+    """Under the fused decode tail the Pallas kernel reads ``attn/wo``:
+    it stays f32 and shared, the other dot weights are cast."""
+    from repro.models import model as model_mod
+
+    cfg = _tiny()
+    model = build_model(cfg, remat=False)
+    params = model.init(jax.random.key(7))
+    with model_mod.use_run_weights(True):
+        eng = RolloutEngine(model, params, cfg=EngineConfig(
+            n_slots=4, prompt_len=8, max_gen_len=6, cache="paged",
+            block_size=4, fused_decode="fused"))
+    _check_run_tree(eng, params, _CAST - {("attn", "wo")})
+    assert eng.stats()["run_weight_casts"] == 1
+
+
+def test_run_weights_off_by_default_on_cpu():
+    """Off the TPU the rule does not engage: the programs read
+    ``params`` itself and no cast is counted."""
+    _, params, eng = _engine(_tiny(), cache="paged", block_size=4)
+    assert eng.run_params is params and eng.params is params
+    eng.update_weights(jax.tree.map(lambda x: x + 1.0, params), 1)
+    assert eng.run_params is eng.params
+    st = eng.stats()
+    assert st["run_weight_casts"] == 0 and st["run_weight_dtype"] is None
+
+
+@pytest.mark.parametrize("platform,precision,on", [
+    ("tpu", None, True), ("tpu", "default", True), ("tpu", "bfloat16", True),
+    ("tpu", "highest", False), ("tpu", "float32", False),
+    ("cpu", None, False), ("gpu", "default", False)])
+def test_run_weight_rule(monkeypatch, platform, precision, on):
+    """The copy is built only where a default f32 dot is already one
+    bf16 pass: a TPU, at the default or bfloat16 matmul precision."""
+    import contextlib
+
+    from repro.models import model as model_mod
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    scope = (jax.default_matmul_precision(precision) if precision
+             else contextlib.nullcontext())
+    with scope:
+        assert (model_mod.run_weight_dtype() is not None) is on
+        with model_mod.use_run_weights(not on):
+            assert (model_mod.run_weight_dtype() is not None) is (not on)

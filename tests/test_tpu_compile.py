@@ -1,6 +1,7 @@
 """Compile-only checks for the TPU v5e: every Pallas kernel at real
-widths, and one whole fused paged decode step of areal-qwen-1.5b at full
-width and depth, compiled by the TPU compiler for a described (not
+widths, one whole fused paged decode step of areal-qwen-1.5b at full
+width and depth, and the rollout's decode and chunk steps on its run
+weights, compiled by the TPU compiler for a described (not
 attached) chip.  Nothing runs; a pass means Mosaic accepted the kernel's
 tiling and fast-memory use and that the kernel is in the compiled
 program (``tpu_custom_call``).  Interpret-mode tests cannot see either.
@@ -10,6 +11,10 @@ module fixture, never at import:
 only one process at a time may load the TPU library, and every test
 worker imports this file.
 """
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -148,3 +153,60 @@ def test_paged_prefill_chunk_step_compiles_at_full_width(one_chip, full_width):
                         i32(rows, CHUNK), cache, i32(rows, e),
                         i32(rows, CHUNK), i32(rows), i32(rows), i32(rows))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def cell_width(one_chip):
+    """The benchmark cell's model (areal-qwen-1.5b at 4 layers, a
+    76,288-row vocabulary and an untied head) with its f32 parameters
+    and with the run weights the engine casts from them, and the cell's
+    paged pool: 32 slots x 544 tokens in f32, as shapes on one described
+    chip."""
+    cfg = dataclasses.replace(QWEN, n_layers=4, vocab_size=76_288,
+                              tie_embeddings=False)
+    model = build_model(cfg, remat=False)
+    put = lambda t: jax.tree.map(                           # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), t)
+    params = model_mod.param_specs(model, cfg, jnp.float32)
+    run = jax.eval_shape(
+        lambda p: model_mod.run_weights(p, jnp.bfloat16), params)
+    slots, max_len = 32, 544
+    cache, tables = model_mod.paged_cache_specs(
+        model, cfg, slots, max_len, BLOCK, slots * (max_len // BLOCK),
+        jnp.float32)
+    return model, {"f32": put(params), "run": put(run)}, put(cache), tables.shape
+
+
+def _weight_converts(hlo: str):
+    """Element counts of the bf16 converts of an optimised HLO module
+    whose operand is weight-sized (at least 2**20 elements)."""
+    sizes = (math.prod(int(d) for d in m.group(1).split(","))
+             for m in re.finditer(r"= bf16\[([\d,]+)\]\S* convert\(", hlo))
+    return sorted(n for n in sizes if n >= 1 << 20)
+
+
+@pytest.mark.parametrize("tree", ["f32", "run"])
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_run_weights_take_the_converts_out_of_rollout_steps(
+        step, tree, one_chip, cell_width):
+    """On f32 weights XLA rounds every stacked layer weight to bf16 in
+    each decode and chunk program, seven converts of 187,170,816
+    elements in all; on the engine's run weights (DESIGN.md §Rollout
+    run weights) no weight-sized convert is left."""
+    from repro.launch import steps
+
+    model, trees, cache, (slots, e) = cell_width
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,    # noqa: E731
+                                          sharding=one_chip)
+    if step == "decode":
+        compiled = _compile(steps.make_paged_serve_step(model), trees[tree],
+                            i32(slots), cache, i32(slots, e))
+    else:
+        compiled = _compile(steps.make_paged_prefill_chunk_step(model),
+                            trees[tree], i32(1, 16), cache, i32(1, e),
+                            i32(1, 16), i32(1), i32(1), i32(1))
+    sizes = _weight_converts(compiled.as_text())
+    if tree == "f32":
+        assert len(sizes) == 7 and sum(sizes) == 187_170_816, sizes
+    else:
+        assert sizes == []
