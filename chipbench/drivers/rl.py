@@ -33,9 +33,10 @@ import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+import arch
 import system
 import weights
-from reference.dense import GRPO, Ref, adam_state
+from reference.grpo import GRPO, adam_state
 from repro.configs.base import RLConfig
 from repro.core import (AsyncScheduler, PPOTrainer, ParameterStore,
                         RolloutEngine, ThreadedRuntime)
@@ -233,7 +234,7 @@ def reference_readings(c: dict, t: dict, seed: int, rec: Record,
     batches: each token's log-probability under the version that sampled
     it, each step's loss, the first moment after step 1 and the change
     after step ``rec.n``."""
-    ref = Ref(c, dtype)
+    ref = arch.reference(c).Ref(c, dtype)
     pad = t["prompt_len"] + t["max_gen_len"]
     hp = dict(t["optimizer"], lr=t["lr"], capacity=pad,
               minibatches=t["ppo_minibatches"])

@@ -2,32 +2,26 @@
 
 Counted from the operation, never from the compiled program, so a
 program that does less work cannot move its own yardstick.  One
-multiply-add is two operations.  ``c`` is a configuration file's dict.
+multiply-add is two operations.  ``c`` is a configuration file's dict;
+the two counts that depend on the architecture, ``matmul_params`` and
+``attention_flops``, are its reference module's
+(``reference/<model_type>.py``).
 """
 from __future__ import annotations
 
-
-def _dims(c):
-    d = c["hidden_size"]
-    hd = d // c["num_attention_heads"]
-    return d, hd, c["num_attention_heads"], c["num_key_value_heads"]
+import arch
 
 
 def matmul_params(c: dict) -> int:
-    """Parameters that take part in a matrix product for every token: the
-    projections and the MLP of every layer, and the LM head (a tied
-    embedding counts once, as the head; an untied embedding is a lookup)."""
-    d, hd, h, kv = _dims(c)
-    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * c["intermediate_size"]
-    return c["num_hidden_layers"] * per_layer + c["vocab_size"] * d
+    """Parameters that take part in a matrix product for every token (an
+    embedding that is only looked up is not among them)."""
+    return arch.reference(c).matmul_params(c)
 
 
 def attention_flops(c: dict, n_keys_total: int) -> float:
-    """Forward operations of attention scores and the weighted sum, for
-    ``n_keys_total`` (query, key) pairs in all, in every layer: 2 matrix
-    products x 2 x heads x head size per pair."""
-    d, hd, h, kv = _dims(c)
-    return 4.0 * h * hd * n_keys_total * c["num_hidden_layers"]
+    """Forward operations of attention for ``n_keys_total`` (query, key)
+    pairs in all, in every layer."""
+    return arch.reference(c).attention_flops(c, n_keys_total)
 
 
 def causal_pairs(length: int, start: int = 0) -> int:

@@ -1,19 +1,11 @@
-"""Plain reference of a Qwen2 decoder and of its GRPO step.
+"""The GRPO step of the AReaL trainer, written plainly, over any
+architecture's reference model.
 
-Straight ``jax.numpy`` over the leaves of ``weights.make``: no kernels,
-no cache, no packing.  Every matrix product runs at
-``Precision.HIGHEST`` in float32.  ``dtype=bfloat16`` computes the same
-thing in bfloat16 at the default precision: the control, which the
-comparison must fail.
-
-Published description followed: pre-norm blocks, rotary embeddings on
-the two halves of each head, grouped-query attention (query head i reads
-key/value head i // (H / KV)), SwiGLU, causal softmax over the published
-vocabulary.  Departures of the system under test that the reference
-follows, so that the comparison sees the system's own arithmetic:
-
-- no q/k/v biases (Qwen2 publishes them; the system has none);
-- tied embeddings where the configuration file says so.
+``Policy`` is what the step reads of a model: an architecture's ``Ref``
+(``reference/<model_type>.py``) subclasses it and writes the forward,
+``hidden`` and ``logits``; ``Policy`` turns them into per-token
+log-probabilities, in blocks of rows.  The step: GRPO advantages,
+Algorithm 1 micro-batches, decoupled PPO (AReaL Eq. 5) and AdamW.
 """
 from __future__ import annotations
 
@@ -21,75 +13,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-EPS = 1e-6
 
-
-class Ref:
-    """The reference model of configuration ``c`` in ``dtype``."""
+class Policy:
+    """A reference model of configuration ``c`` in ``dtype``: float32 at
+    ``Precision.HIGHEST``, or a lower dtype at the default precision (the
+    control, which the comparison must fail).  Subclasses give
+    ``hidden(w, tokens)`` (B, S, d) and ``logits(w, hidden)`` (B, S, V),
+    computed in ``self.dtype`` at ``self.prec``."""
 
     def __init__(self, c: dict, dtype=jnp.float32):
         self.c = c
         self.dtype = jnp.dtype(dtype)
         self.prec = (jax.lax.Precision.HIGHEST if self.dtype == jnp.float32
                      else jax.lax.Precision.DEFAULT)
-        self.hd = c["hidden_size"] // c["num_attention_heads"]
         self._logprobs = jax.jit(self._logprobs_fn)
         self.jit_logits = jax.jit(lambda w, x: self.logits(w, self.hidden(w, x)))
 
     def cast(self, w):
         return {k: v.astype(self.dtype) for k, v in w.items()}
-
-    # ---- forward -----------------------------------------------------------
-    def _mm(self, x, w):
-        return jnp.matmul(x, w, precision=self.prec,
-                          preferred_element_type=jnp.float32).astype(self.dtype)
-
-    def _norm(self, x, scale):
-        xf = x.astype(jnp.float32)
-        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + EPS)
-        return (xf * scale.astype(jnp.float32)).astype(self.dtype)
-
-    def _rope(self, x, pos):
-        hd = x.shape[-1]
-        inv = self.c["rope_theta"] ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-        ang = pos[:, :, None, None].astype(jnp.float32) * inv
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               -1).astype(self.dtype)
-
-    def hidden(self, w, tokens):
-        """Final hidden states (B, S, d) of right-padded ``tokens``."""
-        c, hd = self.c, self.hd
-        b, s = tokens.shape
-        h_, kv_ = c["num_attention_heads"], c["num_key_value_heads"]
-        pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-        causal = jnp.tril(jnp.ones((s, s), bool))
-        h = w["embed"][tokens]
-        for i in range(c["num_hidden_layers"]):
-            x = self._norm(h, w["attn_norm"][i])
-            q = self._rope(self._mm(x, w["wq"][i]).reshape(b, s, h_, hd), pos)
-            k = self._rope(self._mm(x, w["wk"][i]).reshape(b, s, kv_, hd), pos)
-            v = self._mm(x, w["wv"][i]).reshape(b, s, kv_, hd)
-            k = jnp.repeat(k, h_ // kv_, axis=2)
-            v = jnp.repeat(v, h_ // kv_, axis=2)
-            sc = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=self.prec,
-                            preferred_element_type=jnp.float32) * hd ** -0.5
-            p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-            a = jnp.einsum("bhqk,bkhd->bqhd", p.astype(self.dtype), v,
-                           precision=self.prec,
-                           preferred_element_type=jnp.float32).astype(self.dtype)
-            h = h + self._mm(a.reshape(b, s, h_ * hd), w["wo"][i])
-            x = self._norm(h, w["mlp_norm"][i])
-            g = self._mm(x, w["w_gate"][i]).astype(jnp.float32)
-            u = self._mm(x, w["w_up"][i])
-            h = h + self._mm((jax.nn.silu(g).astype(self.dtype) * u), w["w_down"][i])
-        return self._norm(h, w["final_norm"])
-
-    def logits(self, w, hidden):
-        head = w["embed"].T if self.c["tie_word_embeddings"] else w["head"]
-        return jnp.matmul(hidden, head, precision=self.prec,
-                          preferred_element_type=jnp.float32)
 
     def _logprobs_fn(self, w, tokens):
         """(B, S): entry t is log p(tokens[t] | tokens[:t]); entry 0 is 0."""
@@ -159,7 +100,7 @@ class GRPO:
     micro-batches, ``minibatches`` sequential AdamW updates of the
     decoupled PPO objective (AReaL Eq. 5)."""
 
-    def __init__(self, ref: Ref, hp: dict, pad_to: int, rows: int):
+    def __init__(self, ref: Policy, hp: dict, pad_to: int, rows: int):
         self.ref, self.hp, self.pad_to, self.rows = ref, hp, pad_to, rows
         self._grad = jax.jit(jax.value_and_grad(self._loss_fn))
         self._acc = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),

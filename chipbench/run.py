@@ -7,6 +7,10 @@ configuration (``configs/<config>.json``), the traffic mix
 (``traffic/<traffic>.json``, whose ``kind`` picks the driver in
 ``drivers/``), the limits of its comparison (``limits/<workload>.json``)
 and the readers of its per-layer metrics (``metrics/<metric>.py``).
+The configuration's ``model_type`` names its architecture
+(``arch.py``): the reference module ``reference/<model_type>.py``
+(leaves, plain forward, operation counts) and the program module
+``programs/<model_type>.py`` (the system's config and parameter tree).
 
 The run makes its weights and inputs from ``--seed``, warms up, measures
 for about ``--seconds``, then compares what the timed path produced with

@@ -1,9 +1,11 @@
-"""The one module of the benchmark that knows the system under test.
+"""Where the benchmark meets the system under test's model.
 
-It turns a configuration file into the program's ``ModelConfig``, the
-benchmark's weights into the program's parameter tree, and the
-program's trees back into the benchmark's leaf names, so that the
-reference and the comparison never import the program.
+It builds the program's model of a configuration file, puts the
+benchmark's weights into the program's parameter tree and checks that
+tree against the program's own init, and reads the program's trees back
+under the benchmark's leaf names, so that the reference and the
+comparison never import the program.  What depends on the architecture
+is ``programs/<model_type>.py``'s, found through ``arch.program``.
 """
 from __future__ import annotations
 
@@ -19,41 +21,14 @@ ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
 
-from repro.configs.base import ModelConfig  # noqa: E402
+import arch  # noqa: E402
 from repro.models.model import build_model  # noqa: E402
-
-_ATTN = ("wq", "wk", "wv", "wo")
-_MLP = ("w_gate", "w_up", "w_down")
-
-
-def model_config(c: dict) -> ModelConfig:
-    """The program's config of a configuration file's dict ``c``."""
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], tie_embeddings=c["tie_word_embeddings"],
-        rope_theta=float(c["rope_theta"]),
-        norm_type="rmsnorm", parametric_norm=True, act="swiglu")
 
 
 def build(c: dict):
-    return build_model(model_config(c), remat=False)
-
-
-def _tree(w: dict, model):
-    cfg = model.cfg
-    pad = cfg.padded_vocab - cfg.vocab_size
-    unit = {"attn": {k: w[k] for k in _ATTN},
-            "mlp": {k: w[k] for k in _MLP},
-            "attn_norm": {"scale": w["attn_norm"]},
-            "mlp_norm": {"scale": w["mlp_norm"]}}
-    tree = {"embed": {"table": jnp.pad(w["embed"], ((0, pad), (0, 0)))},
-            "units": (unit,), "rem": (),
-            "final_norm": {"scale": w["final_norm"]}}
-    if "head" in w:
-        tree["head"] = {"w": jnp.pad(w["head"], ((0, 0), (0, pad)))}
-    return tree
+    """The program's model of configuration ``c``."""
+    prog = arch.program(c)
+    return build_model(prog.program_config(c), remat=False)
 
 
 def program_params(c: dict, model, w: dict):
@@ -61,7 +36,8 @@ def program_params(c: dict, model, w: dict):
     checked leaf for leaf against the tree the program's own init makes.
     The vocabulary's padding rows are zero."""
     want = jax.eval_shape(lambda: model.init(jax.random.key(0)))
-    tree = jax.jit(lambda w: _tree(w, model))(w)
+    prog = arch.program(c)
+    tree = jax.jit(lambda w: prog.program_tree(c, w, model))(w)
     got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
     if jax.tree.structure(got) != jax.tree.structure(want) or any(
             a.shape != b.shape or a.dtype != b.dtype
@@ -74,17 +50,7 @@ def program_params(c: dict, model, w: dict):
 def leaves(c: dict, tree) -> dict:
     """The benchmark's names for the leaves of a program tree (params or an
     optimizer moment), with the vocabulary's padding cut off."""
-    v = c["vocab_size"]
-    unit = tree["units"][0]
-    out = {k: unit["attn"][k] for k in _ATTN}
-    out.update({k: unit["mlp"][k] for k in _MLP})
-    out["embed"] = tree["embed"]["table"][:v]
-    if "head" in tree:
-        out["head"] = tree["head"]["w"][:, :v]
-    out.update(attn_norm=unit["attn_norm"]["scale"],
-               mlp_norm=unit["mlp_norm"]["scale"],
-               final_norm=tree["final_norm"]["scale"])
-    return out
+    return arch.program(c).leaves(c, tree)
 
 
 def leaf_norms(c: dict, tree) -> dict:
@@ -98,7 +64,3 @@ def leaf_norms(c: dict, tree) -> dict:
 def _norms(c_json, tree):
     return {k: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
             for k, x in leaves(json.loads(c_json), tree).items()}
-
-
-def load_json(path) -> dict:
-    return json.loads(Path(path).read_text())

@@ -1,11 +1,13 @@
-"""Operation counts against values worked out by hand."""
+"""Operation counts against values worked out by hand, through the
+architecture's counts (``model_type`` ``qwen2``)."""
 import pytest
 
 import flops
 
 # d 8, 2 heads of 4, 1 kv head, d_ff 16, 2 layers, vocab 10
-C = {"hidden_size": 8, "num_attention_heads": 2, "num_key_value_heads": 1,
-     "intermediate_size": 16, "num_hidden_layers": 2, "vocab_size": 10}
+C = {"model_type": "qwen2", "hidden_size": 8, "num_attention_heads": 2,
+     "num_key_value_heads": 1, "intermediate_size": 16, "num_hidden_layers": 2,
+     "vocab_size": 10}
 
 
 def test_matmul_params():

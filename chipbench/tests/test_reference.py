@@ -10,9 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import arch
 import system
 import weights
-from reference.dense import Ref
 
 HERE = Path(__file__).parent
 
@@ -29,7 +29,7 @@ def test_reference_logits_match_the_system(tie):
     tok = np.random.default_rng(0).integers(3, c["vocab_size"], (2, 40)).astype(np.int32)
     with jax.default_matmul_precision("highest"), ops.use_backend("jnp"):
         got = np.asarray(jax.jit(lambda p, t: model.forward(p, t)[0])(params, tok))
-    ref = Ref(c)
+    ref = arch.reference(c).Ref(c)
     want = np.asarray(ref.jit_logits(w, jnp.asarray(tok)))
     assert got.shape[-1] == c["vocab_size"]
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
